@@ -612,14 +612,15 @@ def bench_opt_step_time_sharded_stats(iters: int = 10) -> None:
     """Engine step wall-time with stats_reduction="sharded" on an 8-device
     host-platform CPU mesh next to the replicated step on the same shapes.
     Runs in a subprocess: this process must keep seeing one device (the
-    dry-run contract), and XLA only fakes the device count at startup."""
+    dry-run contract), and XLA only fakes the device count at startup.  The
+    child is pinned to ``JAX_PLATFORMS=cpu`` — the row measures the host
+    platform, and a parent that holds a chip keeps it."""
     code = f"""
 import time
 import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.core import sketchy as sk
 from repro.distributed import reduce as dreduce
-from repro.sharding.rules import shard_map
 
 rng = np.random.default_rng(0)
 params = {{f"w{{i}}": jnp.asarray(rng.normal(size=(64, 64)), jnp.float32)
@@ -627,7 +628,7 @@ params = {{f"w{{i}}": jnp.asarray(rng.normal(size=(64, 64)), jnp.float32)
 grads = {{k: jnp.asarray(rng.normal(size=(8,) + v.shape), jnp.float32)
          for k, v in params.items()}}
 gmean = jax.tree.map(lambda g: g.mean(0), grads)
-mesh = jax.make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
 
 def bench(tx, fn, *args):
     state = tx.init(params)
@@ -651,12 +652,13 @@ def sharded(g, s):
         gm = dreduce.pmean(gl, "data")
         with dreduce.local_gradients(gl):
             return tx_s.update(gm, s, params)
-    return shard_map(body, mesh=mesh, in_specs=(P("data"), P()),
+    return jax.shard_map(body, mesh=mesh, in_specs=(P("data"), P()),
                      out_specs=(P(), P()), check_vma=False)(g, s)
 us_s = bench(tx_s, sharded, grads)
 print(f"SHARDED_US={{us_s:.1f}} REPL_US={{us_r:.1f}}")
 """
     env = {**os.environ,
+           "JAX_PLATFORMS": "cpu",
            "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
            "PYTHONPATH": os.pathsep.join(
                [os.path.join(os.path.dirname(os.path.dirname(
@@ -666,9 +668,8 @@ print(f"SHARDED_US={{us_s:.1f}} REPL_US={{us_r:.1f}}")
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                        text=True, env=env, timeout=900)
     if r.returncode != 0:
-        _row("opt_step_time_sharded_stats", 0.0,
-             f"SUBPROCESS_FAILED: {r.stderr[-200:]!r}")
-        return
+        raise RuntimeError("opt_step_time_sharded_stats child failed:\n"
+                           + r.stderr[-4000:])
     kv = dict(tok.split("=") for tok in r.stdout.split() if "=" in tok)
     us_s, us_r = float(kv["SHARDED_US"]), float(kv["REPL_US"])
     _row("opt_step_time_sharded_stats", us_s,

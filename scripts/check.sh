@@ -157,6 +157,7 @@ if [[ "${1:-}" == "fast" ]]; then
   python -m pytest -x -q \
     --ignore=tests/test_trainer.py \
     --ignore=tests/test_distributed.py \
+    --ignore=tests/test_dryrun.py \
     --ignore=tests/test_optim_convex.py \
     --ignore=tests/test_models.py \
     "$@"

@@ -29,6 +29,11 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+# Every contraction on the FD path runs at full f32: a TPU's default f32
+# dot is one bf16 pass, and the Gram feeds an eigh whose small eigenvalues
+# set the preconditioner.
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 
 class FDState(NamedTuple):
     eigvecs: jnp.ndarray  # (d, ell) approximate top eigenvectors U
@@ -79,7 +84,7 @@ def fd_update(state: FDState, new_factor: jnp.ndarray, beta2: float = 1.0,
     M = jnp.concatenate([B, new_factor.astype(compute_dtype)], axis=1)  # (d, ell+r)
 
     if kernels is None:
-        C = M.T @ M
+        C = jnp.matmul(M.T, M, precision=_HIGHEST)
     else:
         C = kernels.gram(M)
     C = 0.5 * (C + C.T)  # symmetrize for eigh stability
@@ -95,7 +100,7 @@ def fd_update(state: FDState, new_factor: jnp.ndarray, beta2: float = 1.0,
     rho_t = lam_top[ell - 1]
 
     inv_sqrt = jnp.where(lam_top > 1e-30, jax.lax.rsqrt(jnp.maximum(lam_top, 1e-30)), 0.0)
-    U_new = (M @ V[:, :ell]) * inv_sqrt[None, :]
+    U_new = jnp.matmul(M, V[:, :ell], precision=_HIGHEST) * inv_sqrt[None, :]
     s_new = lam_top - rho_t  # deflate: last entry becomes exactly 0
 
     return FDState(
@@ -158,7 +163,7 @@ def fd_update_batched(state: FDState, new_factor: jnp.ndarray,
     M = jnp.concatenate([B, new_factor.astype(compute_dtype)], axis=2)
 
     if kernels is None:
-        C = jnp.matmul(jnp.swapaxes(M, -1, -2), M)
+        C = jnp.matmul(jnp.swapaxes(M, -1, -2), M, precision=_HIGHEST)
     else:
         C = kernels.batched_gram(M)
     C = 0.5 * (C + jnp.swapaxes(C, -1, -2))
@@ -172,7 +177,8 @@ def fd_update_batched(state: FDState, new_factor: jnp.ndarray,
 
     inv_sqrt = jnp.where(lam_top > 1e-30,
                          jax.lax.rsqrt(jnp.maximum(lam_top, 1e-30)), 0.0)
-    U_new = jnp.matmul(M, V[..., :ell]) * inv_sqrt[:, None, :]
+    U_new = jnp.matmul(M, V[..., :ell], precision=_HIGHEST) \
+        * inv_sqrt[:, None, :]
     s_new = lam_top - rho_t[..., None]
     if kmask is not None:
         U_new = jnp.where(kmask[:, None, :], U_new, 0.0)
@@ -231,7 +237,7 @@ def _fd_update_batched_quantized(U, s, rho, new_factor, beta2, kernels,
     if kernels is None:
         m = jnp.concatenate(
             [vq.astype(jnp.float32) * colw[:, None, :], A], axis=2)
-        C = jnp.matmul(jnp.swapaxes(m, -1, -2), m)
+        C = jnp.matmul(jnp.swapaxes(m, -1, -2), m, precision=_HIGHEST)
     else:
         C = kernels.batched_gram_mixed(vq, colw, A)
     C = 0.5 * (C + jnp.swapaxes(C, -1, -2))
@@ -257,7 +263,8 @@ def _fd_update_batched_quantized(U, s, rho, new_factor, beta2, kernels,
     w_bot = W[..., ell:, :]                           # (N, r, ell)
 
     if kernels is None:
-        un = jnp.matmul(vq.astype(jnp.float32), w_top) + jnp.matmul(A, w_bot)
+        un = jnp.matmul(vq.astype(jnp.float32), w_top, precision=_HIGHEST) \
+            + jnp.matmul(A, w_bot, precision=_HIGHEST)
         qp = quantize.quantize_stack(un)
     else:
         values, scale_new = kernels.batched_project_quantize(
@@ -352,7 +359,7 @@ def fd_merge_factors_batched(Ba: jnp.ndarray, rho_a: jnp.ndarray,
         M = jnp.pad(M, ((0, 0), (0, 0), (0, pad)))
 
     if kernels is None:
-        C = jnp.matmul(jnp.swapaxes(M, -1, -2), M)
+        C = jnp.matmul(jnp.swapaxes(M, -1, -2), M, precision=_HIGHEST)
     else:
         C = kernels.batched_gram(M)
     C = 0.5 * (C + jnp.swapaxes(C, -1, -2))
@@ -366,7 +373,8 @@ def fd_merge_factors_batched(Ba: jnp.ndarray, rho_a: jnp.ndarray,
 
     inv_sqrt = jnp.where(lam_top > 1e-30,
                          jax.lax.rsqrt(jnp.maximum(lam_top, 1e-30)), 0.0)
-    U_new = jnp.matmul(M, V[..., :ell]) * inv_sqrt[:, None, :]
+    U_new = jnp.matmul(M, V[..., :ell], precision=_HIGHEST) \
+        * inv_sqrt[:, None, :]
     s_new = lam_top - rho_t[..., None]      # deflate: last entry exactly 0
 
     return FDState(eigvecs=U_new, eigvals=s_new,
@@ -445,7 +453,8 @@ def fd_subspace_angle(a, b, k: int = None) -> jnp.ndarray:
     if k is None:
         k = max(Ua.shape[-1] - 1, 1)
     k = min(k, Ua.shape[-1], Ub.shape[-1])
-    C = Ua[..., :k].astype(jnp.float32).T @ Ub[..., :k].astype(jnp.float32)
+    C = jnp.matmul(Ua[..., :k].astype(jnp.float32).T,
+                   Ub[..., :k].astype(jnp.float32), precision=_HIGHEST)
     sv = jnp.linalg.svd(C, compute_uv=False)
     return jnp.arccos(jnp.clip(jnp.min(sv, axis=-1), 0.0, 1.0))
 
@@ -495,8 +504,9 @@ def fd_apply_inverse_root(state: FDState, G: jnp.ndarray, *, exponent: float,
     U = state.eigvecs
     if kernels is not None:
         return kernels.lowrank_apply(U, coeffs, base, G)
-    proj = U.T @ G
-    return base * G + U @ (coeffs[:, None] * proj)
+    proj = jnp.matmul(U.T, G, precision=_HIGHEST)
+    return base * G + jnp.matmul(U, coeffs[:, None] * proj,
+                                 precision=_HIGHEST)
 
 
 def fd_apply_inverse_root_batched(state: FDState, G: jnp.ndarray, *,
@@ -520,5 +530,6 @@ def fd_apply_inverse_root_batched(state: FDState, G: jnp.ndarray, *,
         U = U.values.astype(jnp.float32) * U.scale
     elif kernels is not None:
         return kernels.batched_lowrank_apply(U, coeffs, base, G)
-    proj = jnp.matmul(jnp.swapaxes(U, -1, -2), G)
-    return base[..., None, None] * G + jnp.matmul(U, coeffs[..., None] * proj)
+    proj = jnp.matmul(jnp.swapaxes(U, -1, -2), G, precision=_HIGHEST)
+    return base[..., None, None] * G + jnp.matmul(
+        U, coeffs[..., None] * proj, precision=_HIGHEST)

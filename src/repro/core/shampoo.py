@@ -20,11 +20,16 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, ClassVar, NamedTuple, Optional
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import api, blocking
 from repro.core.transform import GradientTransformation
 from repro.kernels.registry import KernelSet
+
+# full-f32 contractions (a TPU's default f32 dot is one bf16 pass, and the
+# inverse 4th root amplifies error in the statistics' small eigenvalues)
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 @dataclasses.dataclass(frozen=True)
@@ -63,7 +68,7 @@ def _inv_root(m: jnp.ndarray, eps: float, power: float) -> jnp.ndarray:
     lam, V = jnp.linalg.eigh(m + eps * jnp.eye(d, dtype=m.dtype))
     lam = jnp.maximum(lam, eps)
     return jnp.matmul(V * jnp.power(lam, power)[..., None, :],
-                      jnp.swapaxes(V, -1, -2))
+                      jnp.swapaxes(V, -1, -2), precision=_HIGHEST)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -98,8 +103,10 @@ class ShampooPreconditioner:
         # un-normalized EMA (distributed-Shampoo convention; matches the
         # FD recursion of Obs. 6 so rank>=dim recovers Shampoo exactly)
         return ShampooBlockStats(
-            L=self.cfg.beta2 * state.L + G @ G.T,
-            R=self.cfg.beta2 * state.R + G.T @ G,
+            L=self.cfg.beta2 * state.L
+            + jnp.matmul(G, G.T, precision=_HIGHEST),
+            R=self.cfg.beta2 * state.R
+            + jnp.matmul(G.T, G, precision=_HIGHEST),
             PL=state.PL, PR=state.PR)
 
     def refresh(self, state, G, *, count):
@@ -109,7 +116,8 @@ class ShampooPreconditioner:
             PR=_inv_root(state.R, self.cfg.matrix_eps, -0.25))
 
     def precondition(self, state, G, *, count):
-        return state.PL @ G @ state.PR
+        return jnp.matmul(jnp.matmul(state.PL, G, precision=_HIGHEST),
+                          state.PR, precision=_HIGHEST)
 
     # ------------------------------------------- pooled-stack (kernel path)
 
@@ -120,8 +128,10 @@ class ShampooPreconditioner:
             L_inc = self.kernels.batched_gram(jnp.swapaxes(G, -1, -2))
             R_inc = self.kernels.batched_gram(G)
         else:
-            L_inc = jnp.matmul(G, jnp.swapaxes(G, -1, -2))
-            R_inc = jnp.matmul(jnp.swapaxes(G, -1, -2), G)
+            L_inc = jnp.matmul(G, jnp.swapaxes(G, -1, -2),
+                               precision=_HIGHEST)
+            R_inc = jnp.matmul(jnp.swapaxes(G, -1, -2), G,
+                               precision=_HIGHEST)
         return ShampooBlockStats(
             L=self.cfg.beta2 * state.L + L_inc,
             R=self.cfg.beta2 * state.R + R_inc,
@@ -134,7 +144,8 @@ class ShampooPreconditioner:
             PR=_inv_root(state.R, self.cfg.matrix_eps, -0.25))
 
     def precondition_batched(self, state, G, *, count):
-        return jnp.matmul(jnp.matmul(state.PL, G), state.PR)
+        return jnp.matmul(jnp.matmul(state.PL, G, precision=_HIGHEST),
+                          state.PR, precision=_HIGHEST)
 
 
 def shampoo(cfg: ShampooConfig = ShampooConfig()) -> GradientTransformation:

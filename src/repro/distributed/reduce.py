@@ -41,19 +41,18 @@ _local = threading.local()
 def bound_axis_size(axis: str) -> Optional[int]:
     """Size of a bound mesh axis, or None when the name is unbound.
 
-    Inside ``shard_map``/``pmap`` the counting ``psum(1, axis)`` folds to a
-    static Python int at trace time (jax <= 0.4.x has no
-    ``jax.lax.axis_size``); outside, the unbound name raises ``NameError``.
+    Inside ``shard_map``/``pmap`` ``jax.lax.axis_size`` is a static Python
+    int at trace time; outside, the unbound name raises ``NameError``.
     """
     try:
-        return int(jax.lax.psum(1, axis))
+        return jax.lax.axis_size(axis)
     except NameError:
         return None
 
 
 def pmean(x: PyTree, axis: str) -> PyTree:
     """Mean over a bound mesh axis (pytree-polymorphic)."""
-    return jax.tree.map(lambda v: v / jax.lax.psum(1, axis),
+    return jax.tree.map(lambda v: v / jax.lax.axis_size(axis),
                         jax.lax.psum(x, axis))
 
 

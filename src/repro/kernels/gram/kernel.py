@@ -33,6 +33,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+# full-f32 MXU contraction (an f32 dot's default on a TPU is one bf16 pass)
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def _gram_kernel(a_i_ref, a_j_ref, out_ref, *, n_d_tiles: int):
     di = pl.program_id(2)
@@ -46,7 +49,7 @@ def _gram_kernel(a_i_ref, a_j_ref, out_ref, *, n_d_tiles: int):
     a_j = a_j_ref[...].astype(jnp.float32)  # (bd, bk)
     out_ref[...] += jax.lax.dot_general(
         a_i, a_j, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        precision=_HIGHEST, preferred_element_type=jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("bk", "bd", "interpret"))
@@ -91,7 +94,7 @@ def _batched_gram_kernel(a_i_ref, a_j_ref, out_ref):
     # per-block A^T A: contract the streamed d tile, batch the pool dim
     out_ref[...] += jax.lax.dot_general(
         a_i, a_j, (((1,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)
+        precision=_HIGHEST, preferred_element_type=jnp.float32)
 
 
 @functools.partial(jax.jit,
@@ -151,7 +154,7 @@ def _batched_gram_mixed_kernel(vq_ref, a_ref, out_ref):
     m = jnp.concatenate([b, a], axis=2)       # in-register, never HBM
     out_ref[...] += jax.lax.dot_general(
         m, m, (((1,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)
+        precision=_HIGHEST, preferred_element_type=jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("bd", "bn_stack", "interpret"))

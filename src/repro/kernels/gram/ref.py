@@ -7,16 +7,23 @@ bitwise-identical to the per-leaf vmap dispatch it replaced.
 import jax
 import jax.numpy as jnp
 
+# full-f32 contraction on every backend (a TPU's default f32 dot is one
+# bf16 pass)
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def gram_ref(a: jnp.ndarray) -> jnp.ndarray:
     a32 = a.astype(jnp.float32)
-    return a32.T @ a32
+    # contract d in place (no transposed copy), the kernel's contraction
+    return jax.lax.dot_general(a32, a32, (((0,), (0,)), ((), ())),
+                               precision=_HIGHEST)
 
 
 def batched_gram_ref(a: jnp.ndarray) -> jnp.ndarray:
     """C[n] = A[n]^T A[n] for a (N, d, k) stack; f32 accumulation."""
     a32 = a.astype(jnp.float32)
-    return jax.lax.dot_general(a32, a32, (((1,), (1,)), ((0,), (0,))))
+    return jax.lax.dot_general(a32, a32, (((1,), (1,)), ((0,), (0,))),
+                               precision=_HIGHEST)
 
 
 def batched_gram_mixed_ref(vq: jnp.ndarray, colw: jnp.ndarray,
@@ -32,7 +39,8 @@ def batched_gram_mixed_ref(vq: jnp.ndarray, colw: jnp.ndarray,
     r = a.shape[-1]
     m = jnp.concatenate([vq.astype(jnp.float32), a.astype(jnp.float32)],
                         axis=2)
-    c0 = jax.lax.dot_general(m, m, (((1,), (1,)), ((0,), (0,))))
+    c0 = jax.lax.dot_general(m, m, (((1,), (1,)), ((0,), (0,))),
+                             precision=_HIGHEST)
     w = jnp.concatenate(
         [colw.astype(jnp.float32), jnp.ones((N, r), jnp.float32)], axis=1)
     return c0 * w[:, :, None] * w[:, None, :]

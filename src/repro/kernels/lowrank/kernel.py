@@ -31,20 +31,32 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
-def _lowrank_kernel(u_ref, coeffs_ref, base_ref, g_ref, out_ref):
-    u = u_ref[...].astype(jnp.float32)   # (d, ell)
-    g = g_ref[...].astype(jnp.float32)   # (d, bn)
-    coeffs = coeffs_ref[...]             # (1, ell) f32
-    base = base_ref[0, 0]
+# f32 operands take the full-f32 MXU contraction: the default precision
+# of an f32 dot on a TPU is a single bf16 pass, which would round U and G
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _apply_block(u, coeffs, base, g):
+    """``base * g + u diag(coeffs) u^T g`` for one block: u (d, ell) and
+    g (d, bn) f32, coeffs (1, ell) f32, base (1, 1) f32."""
     # P = U^T G : (ell, bn)
     proj = jax.lax.dot_general(u, g, (((0,), (0,)), ((), ())),
+                               precision=_HIGHEST,
                                preferred_element_type=jnp.float32)
     proj = proj * coeffs.reshape(-1, 1)
     expand = jax.lax.dot_general(u, proj, (((1,), (0,)), ((), ())),
+                                 precision=_HIGHEST,
                                  preferred_element_type=jnp.float32)
-    out_ref[...] = (base * g + expand).astype(out_ref.dtype)
+    return base * g + expand
+
+
+def _lowrank_kernel(u_ref, coeffs_ref, base_ref, g_ref, out_ref):
+    out_ref[...] = _apply_block(
+        u_ref[...].astype(jnp.float32), coeffs_ref[...], base_ref[...],
+        g_ref[...].astype(jnp.float32)).astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("bn", "interpret"))
@@ -80,17 +92,12 @@ def lowrank_apply_pallas(u: jnp.ndarray, coeffs: jnp.ndarray, base: jnp.ndarray,
 
 
 def _batched_lowrank_kernel(u_ref, coeffs_ref, base_ref, g_ref, out_ref):
-    u = u_ref[...].astype(jnp.float32)   # (bn_stack, d, ell)
-    g = g_ref[...].astype(jnp.float32)   # (bn_stack, d, bn)
-    coeffs = coeffs_ref[...]             # (bn_stack, ell) f32
-    base = base_ref[...]                 # (bn_stack, 1) f32
-    # P[n] = U[n]^T G[n] : (bn_stack, ell, bn)
-    proj = jax.lax.dot_general(u, g, (((1,), (1,)), ((0,), (0,))),
-                               preferred_element_type=jnp.float32)
-    proj = proj * coeffs[:, :, None]
-    expand = jax.lax.dot_general(u, proj, (((2,), (1,)), ((0,), (0,))),
-                                 preferred_element_type=jnp.float32)
-    out_ref[...] = (base[:, :, None] * g + expand).astype(out_ref.dtype)
+    # each of the program's bn_stack blocks runs the single-block math, so
+    # a batched call is bitwise the single-block kernel on every block
+    for i in range(u_ref.shape[0]):
+        out_ref[i] = _apply_block(
+            u_ref[i].astype(jnp.float32), coeffs_ref[i], base_ref[i],
+            g_ref[i].astype(jnp.float32)).astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("bn", "bn_stack", "interpret"))
@@ -114,13 +121,17 @@ def batched_lowrank_apply_pallas(u: jnp.ndarray, coeffs: jnp.ndarray,
     bn_stack = min(bn_stack, max(N, 1))
     pN = (-N) % bn_stack
     pn = (-n) % bn
-    coeffs2d = coeffs.reshape(N, ell).astype(jnp.float32)
-    base2d = jnp.asarray(base, jnp.float32).reshape(N, 1)
+    # coeffs/base ride as (N, 1, ell)/(N, 1, 1): with the pool dim leading,
+    # every block's last two dims equal the array's, which Mosaic accepts
+    # for any bn_stack (an (N, ell) layout would put bn_stack in the
+    # sublane dim, illegal unless it is a multiple of 8 or N itself)
+    coeffs3d = coeffs.reshape(N, 1, ell).astype(jnp.float32)
+    base3d = jnp.asarray(base, jnp.float32).reshape(N, 1, 1)
     if pN or pn:
         u = jnp.pad(u, ((0, pN), (0, 0), (0, 0)))
         g = jnp.pad(g, ((0, pN), (0, 0), (0, pn)))
-        coeffs2d = jnp.pad(coeffs2d, ((0, pN), (0, 0)))
-        base2d = jnp.pad(base2d, ((0, pN), (0, 0)))
+        coeffs3d = jnp.pad(coeffs3d, ((0, pN), (0, 0), (0, 0)))
+        base3d = jnp.pad(base3d, ((0, pN), (0, 0), (0, 0)))
     Np, _, np_ = g.shape
 
     out = pl.pallas_call(
@@ -128,16 +139,21 @@ def batched_lowrank_apply_pallas(u: jnp.ndarray, coeffs: jnp.ndarray,
         grid=(Np // bn_stack, np_ // bn),
         in_specs=[
             pl.BlockSpec((bn_stack, d, ell), lambda nb, j: (nb, 0, 0)),
-            pl.BlockSpec((bn_stack, ell), lambda nb, j: (nb, 0)),
-            pl.BlockSpec((bn_stack, 1), lambda nb, j: (nb, 0)),
+            pl.BlockSpec((bn_stack, 1, ell), lambda nb, j: (nb, 0, 0)),
+            pl.BlockSpec((bn_stack, 1, 1), lambda nb, j: (nb, 0, 0)),
             pl.BlockSpec((bn_stack, d, bn), lambda nb, j: (nb, 0, j)),
         ],
         out_specs=pl.BlockSpec((bn_stack, d, bn), lambda nb, j: (nb, 0, j)),
         out_shape=jax.ShapeDtypeStruct((Np, d, np_), g.dtype),
         interpret=interpret,
-    )(u, coeffs2d, base2d, g)
+    )(u, coeffs3d, base3d, g)
     return out[:N, :, :n]
 
+
+# The write-back epilogue holds whole (d, ell) and (d, r) blocks per grid
+# step; with their double buffers, d=1024, ell=256, r=768 takes 21.5 MiB,
+# past the 16 MiB default scoped VMEM (a v5e core has 128 MiB).
+_PROJECT_VMEM_LIMIT = 64 * 2**20
 
 # int8 range of the quantized-pool storage format; must mirror
 # core/quantize.py (_INT8_MAX) so the fused requantize epilogue below is
@@ -156,9 +172,11 @@ def _batched_project_quantize_kernel(vq_ref, wtop_ref, a_ref, wbot_ref,
     v = vq_ref[...].astype(jnp.float32)       # (bn_stack, d, k)
     un = jax.lax.dot_general(v, wtop_ref[...],
                              (((2,), (1,)), ((0,), (0,))),
+                             precision=_HIGHEST,
                              preferred_element_type=jnp.float32)
     un += jax.lax.dot_general(a_ref[...].astype(jnp.float32), wbot_ref[...],
                               (((2,), (1,)), ((0,), (0,))),
+                              precision=_HIGHEST,
                               preferred_element_type=jnp.float32)
     absmax = jnp.max(jnp.abs(un), axis=(1, 2), keepdims=True)
     scale = jnp.where(absmax > 0, absmax / _INT8_MAX, 1.0)
@@ -222,6 +240,8 @@ def batched_project_quantize_pallas(vq: jnp.ndarray, w_top: jnp.ndarray,
             jax.ShapeDtypeStruct((Np, d, e), jnp.int8),
             jax.ShapeDtypeStruct((Np, 1, 1), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_PROJECT_VMEM_LIMIT),
         interpret=interpret,
     )(vq, w_top, a, w_bot)
     return values[:N], scale[:N]

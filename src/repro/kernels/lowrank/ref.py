@@ -9,20 +9,28 @@ dispatch it replaced.
 import jax
 import jax.numpy as jnp
 
+# full-f32 contraction on every backend (a TPU's default f32 dot is one
+# bf16 pass)
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def lowrank_apply_ref(u: jnp.ndarray, coeffs: jnp.ndarray, base,
                       g: jnp.ndarray) -> jnp.ndarray:
-    proj = u.T @ g
-    return base * g + u @ (coeffs[:, None] * proj)
+    proj = jax.lax.dot_general(u, g, (((0,), (0,)), ((), ())),
+                               precision=_HIGHEST)
+    return base * g + jnp.matmul(u, coeffs[:, None] * proj,
+                                 precision=_HIGHEST)
 
 
 def batched_lowrank_apply_ref(u: jnp.ndarray, coeffs: jnp.ndarray, base,
                               g: jnp.ndarray) -> jnp.ndarray:
     """Per-pool-block apply: u (N, d, ell), coeffs (N, ell), base (N,),
     g (N, d, n) -> (N, d, n)."""
-    proj = jax.lax.dot_general(u, g, (((1,), (1,)), ((0,), (0,))))
+    proj = jax.lax.dot_general(u, g, (((1,), (1,)), ((0,), (0,))),
+                               precision=_HIGHEST)
     scaled = coeffs[:, :, None] * proj
-    expand = jax.lax.dot_general(u, scaled, (((2,), (1,)), ((0,), (0,))))
+    expand = jax.lax.dot_general(u, scaled, (((2,), (1,)), ((0,), (0,))),
+                                 precision=_HIGHEST)
     return base[:, None, None] * g + expand
 
 
@@ -49,7 +57,7 @@ def batched_project_quantize_ref(vq: jnp.ndarray, w_top: jnp.ndarray,
     and re-quantize per block (round-to-nearest, same rule as
     core/quantize.quantize_stack with no key)."""
     from repro.core import quantize
-    un = jnp.matmul(vq.astype(jnp.float32), w_top) \
-        + jnp.matmul(a.astype(jnp.float32), w_bot)
+    un = jnp.matmul(vq.astype(jnp.float32), w_top, precision=_HIGHEST) \
+        + jnp.matmul(a.astype(jnp.float32), w_bot, precision=_HIGHEST)
     qp = quantize.quantize_stack(un)
     return qp.values, qp.scale

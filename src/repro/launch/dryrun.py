@@ -1,7 +1,16 @@
+"""Multi-pod dry run: a CPU lowering tool.
+
+Lowers and compiles one (arch, shape) cell for a production mesh built from
+fake host devices (``--xla_force_host_platform_device_count``), and reports
+compile time, memory and a roofline estimate.  It pins ``JAX_PLATFORMS=cpu``
+before JAX is imported, so on a machine with a chip it neither builds its
+mesh from the chip nor holds it.
+"""
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
-# --- the two lines above MUST run before any jax-importing module ---------
+# --- the lines above MUST run before any jax-importing module -------------
 import argparse  # noqa: E402
 import json      # noqa: E402
 import sys       # noqa: E402

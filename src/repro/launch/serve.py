@@ -40,6 +40,9 @@ def main() -> None:
                    help="MonitorConfig spec, e.g. window=4,ell=8")
     args = p.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     import numpy as np
     import jax
 

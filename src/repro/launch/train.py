@@ -1,19 +1,35 @@
-"""End-to-end training launcher (runnable at CPU scale; the production mesh
-is exercised via dryrun.py).
+"""End-to-end training launcher.
+
+Runs the training main path — ``make_train_step`` over the configured
+optimizer — on whatever devices JAX finds: the CPU for tests, a TPU chip
+for ``chip_smoke.py``.  The multi-pod mesh is lowered, not run, by
+``dryrun.py``.
 
 Features: config/arch selection, synthetic data pipeline, optimizer choice
 (sketchy/shampoo/adam), async atomic checkpointing + restart, straggler
-monitor, optional int8 gradient compression, optional mesh.
+monitor, data-parallel sharded statistics over all local devices.
+
+``train(parse_args(argv))`` is the in-process entry point; ``main`` is the
+CLI.
 """
 from __future__ import annotations
 
 import argparse
 import json
 import os
-import time
+from typing import Any, Callable, NamedTuple, Optional
 
 
-def main() -> None:
+class TrainRun(NamedTuple):
+    """What one ``train`` call leaves behind."""
+    step_fn: Callable        # the jitted train step
+    params: Any              # final parameters
+    opt_state: Any           # final optimizer state
+    metrics: list            # per step: step, loss, grad_norm, time_s
+
+
+def parse_args(argv=None) -> argparse.Namespace:
+    """Parse the CLI; ``--rank-budget`` comes back as a ``RankBudget``."""
     p = argparse.ArgumentParser()
     p.add_argument("--arch", default="paper-lm-100m")
     p.add_argument("--reduced", action="store_true",
@@ -80,36 +96,31 @@ def main() -> None:
     p.add_argument("--checkpoint-dir", default=None)
     p.add_argument("--checkpoint-every", type=int, default=50)
     p.add_argument("--resume", action="store_true")
-    p.add_argument("--compress-grads", action="store_true")
     p.add_argument("--log-every", type=int, default=10)
     p.add_argument("--metrics-out", default=None)
     p.add_argument("--seed", type=int, default=0)
-    args = p.parse_args()
-
-    import jax
-    import jax.numpy as jnp
-
-    from repro.configs import registry
-    from repro.core.factory import OptimizerConfig, make_optimizer
-    from repro.data.pipeline import DataConfig, SyntheticLM
-    from repro.models import model as model_lib
-    from repro.train import checkpoint as ckpt_lib
-    from repro.train.elastic import StragglerMonitor
-    from repro.train.trainer import make_train_step
-
-    cfg = registry.get_reduced(args.arch) if args.reduced \
-        else registry.get_config(args.arch)
-    rank_budget = None
+    args = p.parse_args(argv)
     if args.rank_budget:
         from repro.core.sketchy import RankBudget
         from repro.launch.flags import parse_kv_spec
-        rank_budget = parse_kv_spec(
+        args.rank_budget = parse_kv_spec(
             args.rank_budget, RankBudget,
             aliases={"every": "realloc_every"},
             error=lambda m: p.error(f"--rank-budget: {m}"))
+    return args
+
+
+def configs(args: argparse.Namespace) -> tuple:
+    """The (ModelConfig, OptimizerConfig) pair ``train(args)`` runs."""
+    from repro.configs import registry
+    from repro.core.factory import OptimizerConfig
+
+    cfg = registry.get_reduced(args.arch) if args.reduced \
+        else registry.get_config(args.arch)
     opt_cfg = OptimizerConfig(
         name=args.optimizer, learning_rate=args.lr, total_steps=args.steps,
-        rank=args.rank, rank_budget=rank_budget, block_size=args.block_size,
+        rank=args.rank, rank_budget=args.rank_budget,
+        block_size=args.block_size,
         update_every=args.update_every, weight_decay=1e-4,
         kernel_backend=args.kernel_backend,
         refresh_schedule=args.refresh_schedule,
@@ -118,6 +129,25 @@ def main() -> None:
         second_moment_dtype=args.second_moment_dtype,
         quantized_epilogue=args.quantized_epilogue,
         stats_reduction=args.stats_reduction)
+    return cfg, opt_cfg
+
+
+def train(args: argparse.Namespace,
+          on_step: Optional[Callable[[dict], None]] = None) -> TrainRun:
+    """Train for ``args.steps`` steps in this process; ``on_step`` sees
+    each step's metrics as soon as the step has finished on the device."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.factory import make_optimizer
+    from repro.data.pipeline import DataConfig, SyntheticLM
+    from repro.launch.mesh import make_mesh
+    from repro.models import model as model_lib
+    from repro.train import checkpoint as ckpt_lib
+    from repro.train.elastic import StragglerMonitor
+    from repro.train.trainer import make_train_step
+
+    cfg, opt_cfg = configs(args)
     tx = make_optimizer(opt_cfg)
 
     data = SyntheticLM(DataConfig(
@@ -142,12 +172,13 @@ def main() -> None:
     dp_mesh = None
     if args.stats_reduction == "sharded":
         ndev = len(jax.devices())
-        if ndev > 1 and args.batch % ndev == 0:
-            dp_mesh = jax.make_mesh((ndev,), ("data",))
-            print(f"sharded stats over data axis ({ndev} devices)")
-        else:
-            print(f"sharded stats requested but devices={ndev} "
-                  f"batch={args.batch}; falling back to replicated")
+        if ndev < 2 or args.batch % ndev:
+            raise ValueError(
+                f"--stats-reduction sharded needs more than one device and a "
+                f"batch divisible by the device count; found {ndev} "
+                f"device(s) and --batch {args.batch}")
+        dp_mesh = make_mesh((ndev,), ("data",))
+        print(f"sharded stats over data axis ({ndev} devices)")
     # make_train_step jits with params/opt_state donated; the async
     # checkpointer snapshots to host before the next step consumes them
     step_fn = make_train_step(cfg, tx, data_parallel_mesh=dp_mesh)
@@ -163,10 +194,14 @@ def main() -> None:
         params, opt_state, metrics = step_fn(params, opt_state, batch)
         loss = float(metrics["loss"])
         dt = monitor.stop()
+        gnorm = float(metrics["grad_norm"])
+        metrics_log.append({"step": step, "loss": loss, "grad_norm": gnorm,
+                            "time_s": dt})
+        if on_step is not None:
+            on_step(metrics_log[-1])
         if step % args.log_every == 0 or step == args.steps - 1:
             print(f"step {step:5d} loss {loss:.4f} "
-                  f"gnorm {float(metrics['grad_norm']):.3f} {dt*1e3:.0f}ms")
-            metrics_log.append({"step": step, "loss": loss, "time_s": dt})
+                  f"gnorm {gnorm:.3f} {dt*1e3:.0f}ms")
         if ckpt and step and step % args.checkpoint_every == 0:
             ckpt.save(step, (params, opt_state))
     if ckpt:
@@ -179,6 +214,15 @@ def main() -> None:
         os.makedirs(os.path.dirname(args.metrics_out) or ".", exist_ok=True)
         with open(args.metrics_out, "w") as f:
             json.dump(metrics_log, f, indent=2)
+    return TrainRun(step_fn=step_fn, params=params, opt_state=opt_state,
+                    metrics=metrics_log)
+
+
+def main(argv=None) -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    args = parse_args(argv)
+    enable_compile_cache()
+    train(args)
 
 
 if __name__ == "__main__":

@@ -20,7 +20,7 @@ from jax.sharding import PartitionSpec as P
 from repro.models.config import ModelConfig
 from repro.models.layers import apply_rope, rms_norm
 from repro.sharding import rules as rules_lib
-from repro.sharding.rules import axis_extent, constrain, shard_map
+from repro.sharding.rules import axis_extent, constrain
 
 NEG_INF = -1e30
 
@@ -145,7 +145,7 @@ def _chunk_attend(q_chunk: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             qs = jnp.asarray(q_start, jnp.int32)
 
             @functools.partial(
-                shard_map, mesh=rules.mesh,
+                jax.shard_map, mesh=rules.mesh,
                 in_specs=(P(batch_ax, model_ax, None, None, None),
                           P(batch_ax, None, None, None),
                           P(batch_ax, None, None, None), P()),

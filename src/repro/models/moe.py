@@ -17,7 +17,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.models.config import ModelConfig
 from repro.sharding import rules as rules_lib
-from repro.sharding.rules import axis_extent, constrain, shard_map
+from repro.sharding.rules import axis_extent, constrain
 
 
 def moe_params_shape(cfg: ModelConfig) -> dict:
@@ -96,7 +96,7 @@ def _moe_routed_shard_map(cfg: ModelConfig, p: dict, x: jnp.ndarray,
          "w_down": P(model_ax, None, fsdp_ax)},
     )
 
-    @functools.partial(shard_map, mesh=rules.mesh,
+    @functools.partial(jax.shard_map, mesh=rules.mesh,
                        in_specs=in_specs,
                        out_specs=P(batch_ax, None, None),
                        check_vma=False)

@@ -20,19 +20,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 _local = threading.local()
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None):
-    """Version-compat shard_map: ``jax.shard_map`` (new jax) or
-    ``jax.experimental.shard_map`` (<= 0.4.x, where ``check_vma`` is spelled
-    ``check_rep``)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        kw = {} if check_vma is None else {"check_vma": check_vma}
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as sm_old
-    kw = {} if check_vma is None else {"check_rep": check_vma}
-    return sm_old(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-
 # logical axis -> mesh axis (tuple = sharded over multiple mesh axes)
 DEFAULT_LOGICAL_RULES = {
     "batch": ("pod", "data"),     # DP over pod + data

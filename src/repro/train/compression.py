@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import quantize
-from repro.sharding.rules import shard_map
 
 PyTree = Any
 
@@ -45,10 +44,9 @@ def quantized_psum(g: jnp.ndarray, axes: Sequence[str], key) -> jnp.ndarray:
     summed = q.astype(jnp.int32)
     for a in axes:
         summed = jax.lax.psum(summed, a)
-    # axis extent without jax.lax.axis_size (absent in jax <= 0.4.x)
-    n = jax.lax.psum(1, axes[0])
-    for a in axes[1:]:
-        n *= jax.lax.psum(1, a)
+    n = 1
+    for a in axes:
+        n *= jax.lax.axis_size(a)
     return (summed.astype(jnp.float32) * scale / n).astype(g.dtype)
 
 
@@ -67,7 +65,7 @@ def compressed_mean_grads(grads: PyTree, mesh: Mesh,
     flat, treedef = jax.tree.flatten(grads)
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=tuple(P() for _ in flat), out_specs=tuple(P() for _ in flat),
         check_vma=False)
     def reduce_all(*leaves):

@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.sharding.rules import shard_map
 
 
 def gpipe_apply(mesh: Mesh, axis: str, layers, block_fn: Callable,
@@ -29,6 +28,11 @@ def gpipe_apply(mesh: Mesh, axis: str, layers, block_fn: Callable,
     layers: pytree stacked on dim0 with size L, L % n_stages == 0;
     x: (B, ...) activations, B % microbatches == 0.
     """
+    # the stages exchange activations with collectives inside shard_map;
+    # Explicit mesh axes (``jax.make_mesh``'s default) would also type the
+    # scalars of the backward pass as sharded, which plain ``jax.grad``
+    # outside a ``jax.set_mesh`` context rejects — run over Auto axes
+    mesh = Mesh(mesh.devices, mesh.axis_names)
     n_stages = mesh.shape[axis]
     B = x.shape[0]
     assert B % microbatches == 0, (B, microbatches)
@@ -38,7 +42,7 @@ def gpipe_apply(mesh: Mesh, axis: str, layers, block_fn: Callable,
     layer_specs = jax.tree.map(lambda _: P(axis), layers)
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(layer_specs, P()), out_specs=P(),
         check_vma=False)
     def run(local_layers, xm):

@@ -50,7 +50,7 @@ def make_train_step(cfg: ModelConfig, tx: GradientTransformation, *,
     with explicit shardings).
 
     With ``data_parallel_mesh`` the whole step runs inside the
-    ``sharding/rules.shard_map`` wrapper with the batch split over
+    ``jax.shard_map`` with the batch split over
     ``dp_axes``: each shard computes gradients on its local slice, the
     chain consumes the dp-mean gradients (int-free psum — clipping,
     grafting and momentum see exactly what a replicated run sees), and the
@@ -141,7 +141,7 @@ def make_train_step(cfg: ModelConfig, tx: GradientTransformation, *,
             if key == "positions":
                 return P(None, axes)
             return P(axes)
-        step = rules_lib.shard_map(
+        step = jax.shard_map(
             shard_body, mesh=mesh,
             in_specs=(P(), P(), {k: batch_spec(k) for k in batch}),
             out_specs=(P(), P(), P()), check_vma=False)

@@ -202,7 +202,12 @@ def seed_shampoo(cfg: ShampooConfig = ShampooConfig()) -> GradientTransformation
                 ShampooDiagLeaf(acc=acc)
 
         gb = blocking.to_blocks(g32, info)
-        L = cfg.beta2 * st.L + jnp.einsum("sij,skj->sik", gb, gb)
+        gbT = jnp.swapaxes(gb, -1, -2)
+        # L is the Gram of the transposed blocks, contracted in the order
+        # the engine's batched Gram uses (the CPU backend rounds an
+        # in-place minor-dim contraction differently, and the inverse 4th
+        # root amplifies that difference in the null space)
+        L = cfg.beta2 * st.L + jnp.einsum("sji,sjk->sik", gbT, gbT)
         R = cfg.beta2 * st.R + jnp.einsum("sji,sjk->sik", gb, gb)
 
         def refresh(_):

@@ -200,7 +200,6 @@ def test_async_parity_under_sharded_stats():
     r = subprocess.run([sys.executable, "-c", r"""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.sharding.rules import shard_map
 from repro.core import api, sketchy as sk
 from repro.distributed import reduce as dreduce
 
@@ -216,7 +215,7 @@ def make_step(tx):
         gm = dreduce.pmean(gl, "data")
         with dreduce.local_gradients(gl):
             return tx.update(gm, s, params)
-    return jax.jit(shard_map(body, mesh=mesh, in_specs=(P("data"), P()),
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P("data"), P()),
                              out_specs=(P(), P()), check_vma=False))
 
 for sched in ("synchronized", "staggered"):

@@ -87,3 +87,47 @@ def test_train_step_donates_buffers():
         # step (donation means outputs alias inputs, nothing accumulates)
         assert max(counts) - min(counts) <= 2, (mode, counts)
         del params, state, old_p, old_s, tx, step
+
+
+def test_launcher_runs_in_process_and_returns_every_step():
+    """``train(parse_args(argv))`` is the launcher's in-process entry point:
+    one metrics entry per step, reported to ``on_step`` as each finishes."""
+    from repro.launch import train as train_lib
+    args = train_lib.parse_args(
+        ["--reduced", "--steps", "3", "--batch", "4", "--seq", "16",
+         "--rank", "4", "--block-size", "32", "--update-every", "2"])
+    seen = []
+    run = train_lib.train(args, on_step=seen.append)
+    assert [m["step"] for m in run.metrics] == [0, 1, 2]
+    assert seen == run.metrics
+    assert all(np.isfinite(m["loss"]) for m in run.metrics)
+
+
+def test_sharded_stats_without_devices_raises():
+    """--stats-reduction sharded on one device is an error, not a silent
+    fallback to replicated statistics."""
+    import pytest
+    from repro.launch import train as train_lib
+    args = train_lib.parse_args(
+        ["--reduced", "--steps", "1", "--batch", "4", "--seq", "16",
+         "--stats-reduction", "sharded"])
+    with pytest.raises(ValueError, match="more than one device"):
+        train_lib.train(args)
+
+
+def test_compile_cache_dir(monkeypatch, tmp_path):
+    """The cache goes where JAX_COMPILATION_CACHE_DIR says (JAX reads it
+    itself; the helper sets nothing), else to ``<checkout>/.jax_cache``."""
+    from repro.launch import compile_cache
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert compile_cache.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == prev
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        want = compile_cache.CACHE_DIR
+        assert want.endswith(".jax_cache")
+        assert compile_cache.enable_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
