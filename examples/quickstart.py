@@ -142,9 +142,10 @@ wall-clock step time; none of them changes the statistics stream:
     (tests/test_async_refresh.py).  The direction's compiled critical path
     drops every eigh call site (``opt_step_time_async_refresh`` row:
     ~15x shorter at refresh boundaries on the multileaf CPU bench).
-  * ``profile_annotations=True`` — named_scope + profiler.TraceAnnotation
-    spans around update_stats/refresh/precondition/commit (and the
-    butterfly merge rounds), for reading the overlap off a profiler trace.
+  * The engine's phases always carry ``precond/...`` name scopes
+    (update_stats/refresh/precondition/commit, and the butterfly merge
+    rounds), HLO metadata that costs nothing on the device, for reading
+    the overlap off a profiler trace.
 
 ``make_train_step`` jits with params and optimizer state DONATED
 (``donate_argnums=(0, 1)``): the step reuses the input buffers for its

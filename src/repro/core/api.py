@@ -44,7 +44,6 @@ compute and restores them with ``tag_like`` after.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 from typing import Any, Callable, NamedTuple, Optional, Protocol, runtime_checkable
 
@@ -373,10 +372,6 @@ class EngineConfig:
     # ``refresh_mode="async"`` the reallocation rides the pending-slot
     # refresh and commits at t+1 with it (step-shifted parity preserved).
     realloc_every: int = 0
-    # Emit jax.named_scope + jax.profiler.TraceAnnotation spans around the
-    # engine's update_stats / refresh-launch / commit / precondition phases
-    # so the refresh leaving the critical path is visible in a device trace.
-    profile_annotations: bool = False
     state_dtype: Any = jnp.float32
     # OCO learners (S-AdaGrad, Alg. 2) precondition a d-vector with a full
     # d x d sketch: treat 1-D leaves as a single (d, 1) matrix block instead
@@ -542,18 +537,6 @@ def _mark_transient(tree: PyTree) -> PyTree:
                           dataclasses.replace(x.meta, transient=True))
         return x
     return jax.tree.map(one, tree, is_leaf=_is_tagged)
-
-
-@contextlib.contextmanager
-def _span(name: str, enabled: bool):
-    """Profiling span: a ``jax.named_scope`` (HLO op metadata — shows up in
-    device traces, zero runtime cost) plus a ``jax.profiler.TraceAnnotation``
-    (host-side trace event).  Disabled => pure passthrough."""
-    if not enabled:
-        yield
-        return
-    with jax.named_scope(name), jax.profiler.TraceAnnotation(name):
-        yield
 
 
 def _index_unblocked(tree: PyTree, i: int) -> PyTree:
@@ -781,7 +764,6 @@ def scale_by_preconditioner(precond: Preconditioner,
             vrefresh = lambda s, G: refresh_sharded_b(
                 s, G, count=count, axis=cfg.stats_axis, axis_size=axis_size)
         is_async = cfg.refresh_mode == "async" and state.pending is not None
-        spans = cfg.profile_annotations
         realloc_fn = getattr(precond, "realloc_pools", None)
         do_realloc = (cfg.realloc_every > 0 and realloc_fn is not None
                       and len(index.groups) > 0)
@@ -801,6 +783,8 @@ def scale_by_preconditioner(precond: Preconditioner,
                 ((count % period) == 0) & (count > 0),
                 lambda r: realloc_fn(index.groups, r), lambda r: r, raws)
 
+        # the precond/... name scopes are HLO op metadata, free on the
+        # device; the benchmark's trace readers (bench/metrics) match them
         new_pools, pooled_dirs = {}, {}
         new_pending = {} if is_async else None
         if not is_async:
@@ -809,9 +793,9 @@ def scale_by_preconditioner(precond: Preconditioner,
             for grp in index.groups:
                 gb_stats = packed_stats[grp.key]
                 raw = pool_compute(state.pools[grp.key])
-                with _span("precond/update_stats", spans):
+                with jax.named_scope("precond/update_stats"):
                     raw = update_stats_b(raw, gb_stats, count)
-                with _span("precond/refresh", spans):
+                with jax.named_scope("precond/refresh"):
                     raws[grp.key] = refresh_group(grp, raw, gb_stats, count,
                                                   vrefresh)
             raws = maybe_realloc(raws)
@@ -820,7 +804,7 @@ def scale_by_preconditioner(precond: Preconditioner,
             # exactly what the former single fused loop did, value for value.
             for gi, grp in enumerate(index.groups):
                 raw = raws[grp.key]
-                with _span("precond/precondition", spans):
+                with jax.named_scope("precond/precondition"):
                     pooled_dirs[grp.key] = precondition_b(
                         raw, packed[grp.key], count)
                 new_pools[grp.key] = quantize.requantize_pool(
@@ -844,16 +828,16 @@ def scale_by_preconditioner(precond: Preconditioner,
                 gb_stats = packed_stats[grp.key]
                 slot = state.pending[grp.key]
                 live = state.pools[grp.key]
-                with _span("precond/commit", spans):
+                with jax.named_scope("precond/commit"):
                     committed = tag_like(live, pool.commit_select(
                         slot.valid.value, untag(slot.stats), untag(live)))
                 raw = pool_compute(committed)
-                with _span("precond/update_stats", spans):
+                with jax.named_scope("precond/update_stats"):
                     raw = update_stats_b(raw, gb_stats, count)
-                with _span("precond/precondition", spans):
+                with jax.named_scope("precond/precondition"):
                     pooled_dirs[grp.key] = precondition_b(
                         raw, packed[grp.key], count)
-                with _span("precond/refresh_launch", spans):
+                with jax.named_scope("precond/refresh_launch"):
                     refreshed[grp.key] = refresh_group(grp, raw, gb_stats,
                                                        count, vrefresh)
                 raws_pre[grp.key] = raw
@@ -935,11 +919,20 @@ def scale_by_preconditioner(precond: Preconditioner,
 # Labelled composition + hyperparameters-in-state
 
 
+def stage_scope(name: str) -> str:
+    """The name scope of a ``named_chain`` stage's update: ``opt/<name>``,
+    except that the direction stage (state key ``precond``) is
+    ``opt/direction``, so that no scope outside the engine's own contains
+    ``precond/``."""
+    return "opt/" + ("direction" if name == "precond" else name)
+
+
 def named_chain(*stages) -> GradientTransformation:
     """``chain`` with labelled stages: state is ``{name: member_state}``.
 
     Stage names become checkpoint-manifest path components and are the lookup
-    key for ``get_stage`` — no positional index guessing.
+    key for ``get_stage`` — no positional index guessing.  Each stage's
+    update runs under the name scope ``stage_scope(name)``.
     """
     names = [n for n, _ in stages]
     if len(set(names)) != len(names):
@@ -951,7 +944,9 @@ def named_chain(*stages) -> GradientTransformation:
     def update_fn(updates, state, params=None):
         new_state = {}
         for name, t in stages:
-            updates, new_state[name] = t.update(updates, state[name], params)
+            with jax.named_scope(stage_scope(name)):
+                updates, new_state[name] = t.update(updates, state[name],
+                                                    params)
         return updates, new_state
 
     return GradientTransformation(init_fn, update_fn)

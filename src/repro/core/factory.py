@@ -56,9 +56,6 @@ class OptimizerConfig:
     # default) | "async" (launched at t into a double-buffered pending
     # slot, committed at t+1 — eigh + merge leave the step's critical path)
     refresh_mode: str = "inline"
-    # profiling spans around the engine's update/refresh/precondition
-    # phases (jax.named_scope + profiler.TraceAnnotation)
-    profile_annotations: bool = False
     # diagonal-fallback damping for vector/scalar leaves; None keeps the
     # historical graft_eps coupling (seed parity).
     diag_eps: Optional[float] = None
@@ -94,7 +91,6 @@ def _direction(cfg: OptimizerConfig, beta2) -> transform.GradientTransformation:
             start_preconditioning_step=cfg.start_preconditioning_step,
             refresh_schedule=cfg.refresh_schedule,
             refresh_mode=cfg.refresh_mode,
-            profile_annotations=cfg.profile_annotations,
             diag_eps=cfg.diag_eps,
             kernel_backend=cfg.kernel_backend,
             second_moment_dtype=cfg.second_moment_dtype,
@@ -107,7 +103,6 @@ def _direction(cfg: OptimizerConfig, beta2) -> transform.GradientTransformation:
             start_preconditioning_step=cfg.start_preconditioning_step,
             refresh_schedule=cfg.refresh_schedule,
             refresh_mode=cfg.refresh_mode,
-            profile_annotations=cfg.profile_annotations,
             diag_eps=cfg.diag_eps,
             kernel_backend=cfg.kernel_backend,
             second_moment_dtype=cfg.second_moment_dtype))

@@ -46,7 +46,6 @@ class ShampooConfig:
     # "inline" (parity default) | "async": root recompute launched at step t
     # commits at t+1 via the engine's pending slot (core/api.py)
     refresh_mode: str = "inline"
-    profile_annotations: bool = False
     state_dtype: Any = jnp.float32
     # kernel backend for the pooled stat-update Grams: "pallas"|"xla"|"auto"
     kernel_backend: str = "auto"
@@ -158,7 +157,6 @@ def shampoo(cfg: ShampooConfig = ShampooConfig()) -> GradientTransformation:
             graft=cfg.graft, graft_eps=cfg.graft_eps, diag_eps=cfg.diag_eps,
             refresh_schedule=cfg.refresh_schedule,
             refresh_mode=cfg.refresh_mode,
-            profile_annotations=cfg.profile_annotations,
             kernel_backend=cfg.kernel_backend,
             second_moment_dtype=cfg.second_moment_dtype,
             state_dtype=cfg.state_dtype))

@@ -119,8 +119,6 @@ class SketchyConfig:
     # butterfly merge rounds leave the update direction's critical path
     # (engine refresh pipeline, core/api.py)
     refresh_mode: str = "inline"
-    # profiling spans around the engine phases (core/api.py _span)
-    profile_annotations: bool = False
     exponent: float = -0.25         # per-side inverse root (Alg. 3)
     state_dtype: Any = jnp.float32
     # kernel backend for the pooled hot path (engine-resolved KernelSet):
@@ -391,7 +389,6 @@ def sketchy(cfg: SketchyConfig = SketchyConfig()) -> GradientTransformation:
             graft=cfg.graft, graft_eps=cfg.graft_eps, diag_eps=cfg.diag_eps,
             refresh_schedule=cfg.refresh_schedule,
             refresh_mode=cfg.refresh_mode,
-            profile_annotations=cfg.profile_annotations,
             kernel_backend=cfg.kernel_backend,
             second_moment_dtype=cfg.second_moment_dtype,
             quantized_epilogue=cfg.quantized_epilogue,

@@ -77,6 +77,7 @@ def gram_pallas(a: jnp.ndarray, *, bk: int = 128, bd: int = 256,
         out_specs=pl.BlockSpec((bk, bk), lambda i, j, di: (i, j)),
         # accumulate in f32 regardless of input dtype (MXU-style)
         out_shape=jax.ShapeDtypeStruct((kp, kp), jnp.float32),
+        name="gram",
         interpret=interpret,
     )(a, a)
     return out[:k, :k]  # f32 accumulator result (FD consumes f32)
@@ -134,6 +135,7 @@ def batched_gram_pallas(a: jnp.ndarray, *, bk: int = 128, bd: int = 256,
         out_specs=pl.BlockSpec((bn_stack, bk, bk),
                                lambda n, i, j, di: (n, i, j)),
         out_shape=jax.ShapeDtypeStruct((Np, kp, kp), jnp.float32),
+        name="batched_gram",
         interpret=interpret,
     )(a, a)
     return out[:N, :k, :k]
@@ -200,6 +202,7 @@ def batched_gram_mixed_pallas(vq: jnp.ndarray, colw: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((bn_stack, K, K), lambda n, di: (n, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((Np, K, K), jnp.float32),
+        name="batched_gram_mixed",
         interpret=interpret,
     )(vq, a)
     out = out[:N]

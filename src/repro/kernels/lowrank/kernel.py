@@ -86,6 +86,7 @@ def lowrank_apply_pallas(u: jnp.ndarray, coeffs: jnp.ndarray, base: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((d, bn), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((d, np_), g.dtype),
+        name="lowrank_apply",
         interpret=interpret,
     )(u, coeffs2d, base2d, g)
     return out[:, :n]
@@ -145,6 +146,7 @@ def batched_lowrank_apply_pallas(u: jnp.ndarray, coeffs: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((bn_stack, d, bn), lambda nb, j: (nb, 0, j)),
         out_shape=jax.ShapeDtypeStruct((Np, d, np_), g.dtype),
+        name="batched_lowrank_apply",
         interpret=interpret,
     )(u, coeffs3d, base3d, g)
     return out[:N, :, :n]
@@ -242,6 +244,7 @@ def batched_project_quantize_pallas(vq: jnp.ndarray, w_top: jnp.ndarray,
         ],
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_PROJECT_VMEM_LIMIT),
+        name="batched_project_quantize",
         interpret=interpret,
     )(vq, w_top, a, w_bot)
     return values[:N], scale[:N]

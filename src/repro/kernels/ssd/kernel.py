@@ -90,6 +90,7 @@ def ssd_pallas(u: jnp.ndarray, dlog: jnp.ndarray, Bm: jnp.ndarray,
         out_specs=pl.BlockSpec((1, Q, HT, P), lambda b, h, c: (b, c, h, 0)),
         out_shape=jax.ShapeDtypeStruct((B, S, H, P), u.dtype),
         scratch_shapes=[pltpu.VMEM((HT, P, N), jnp.float32)],
+        name="ssd",
         interpret=interpret,
     )(u, dlog, Bm, Cm)
     return out

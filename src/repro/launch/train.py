@@ -10,11 +10,16 @@ Features: config/arch selection, synthetic data pipeline, optimizer choice
 monitor, data-parallel sharded statistics over all local devices.
 
 ``train(parse_args(argv))`` is the in-process entry point; ``main`` is the
-CLI.
+CLI.  To see where an iteration's time goes, run it under the profiler with
+the loop's host spans on (``--profile-annotations``)::
+
+    with jax.profiler.trace(log_dir):
+        train(parse_args([..., "--profile-annotations"]))
 """
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import os
 from typing import Any, Callable, NamedTuple, Optional
@@ -83,8 +88,12 @@ def parse_args(argv=None) -> argparse.Namespace:
                         "committed at t+1, so the eigh + butterfly merge "
                         "overlap with the next step's forward/backward")
     p.add_argument("--profile-annotations", action="store_true",
-                   help="emit named_scope/TraceAnnotation spans around the "
-                        "engine's update/refresh/precondition phases")
+                   help="host trace spans in the training loop: a 'train' "
+                        "step annotation per iteration holding train/data, "
+                        "train/dispatch, train/fetch and train/checkpoint, "
+                        "on the profiler's clock (seen under "
+                        "jax.profiler.trace); the compiled step's name "
+                        "scopes are always on")
     p.add_argument("--stats-reduction", default="replicated",
                    choices=["replicated", "sharded"],
                    help="second-moment maintenance across data-parallel "
@@ -125,7 +134,6 @@ def configs(args: argparse.Namespace) -> tuple:
         kernel_backend=args.kernel_backend,
         refresh_schedule=args.refresh_schedule,
         refresh_mode=args.refresh_mode,
-        profile_annotations=args.profile_annotations,
         second_moment_dtype=args.second_moment_dtype,
         quantized_epilogue=args.quantized_epilogue,
         stats_reduction=args.stats_reduction)
@@ -188,24 +196,37 @@ def train(args: argparse.Namespace,
     n_params = sum(x.size for x in jax.tree.leaves(params))
     print(f"arch={cfg.name} params={n_params/1e6:.1f}M optimizer={args.optimizer}")
 
+    if args.profile_annotations:
+        step_span = lambda s: jax.profiler.StepTraceAnnotation(
+            "train", step_num=s)
+        span = jax.profiler.TraceAnnotation
+    else:
+        step_span = span = lambda _: contextlib.nullcontext()
     for step in range(start_step, args.steps):
-        batch = {k: jnp.asarray(v) for k, v in data.batch(step).items()}
-        monitor.start()
-        params, opt_state, metrics = step_fn(params, opt_state, batch)
-        loss = float(metrics["loss"])
-        dt = monitor.stop()
-        gnorm = float(metrics["grad_norm"])
-        metrics_log.append({"step": step, "loss": loss, "grad_norm": gnorm,
-                            "time_s": dt})
-        if on_step is not None:
-            on_step(metrics_log[-1])
-        if step % args.log_every == 0 or step == args.steps - 1:
-            print(f"step {step:5d} loss {loss:.4f} "
-                  f"gnorm {gnorm:.3f} {dt*1e3:.0f}ms")
-        if ckpt and step and step % args.checkpoint_every == 0:
-            ckpt.save(step, (params, opt_state))
+        with step_span(step):
+            with span("train/data"):
+                batch = {k: jnp.asarray(v)
+                         for k, v in data.batch(step).items()}
+            monitor.start()
+            with span("train/dispatch"):
+                params, opt_state, metrics = step_fn(params, opt_state, batch)
+            with span("train/fetch"):
+                loss = float(metrics["loss"])
+                dt = monitor.stop()
+                gnorm = float(metrics["grad_norm"])
+            metrics_log.append({"step": step, "loss": loss,
+                                "grad_norm": gnorm, "time_s": dt})
+            if on_step is not None:
+                on_step(metrics_log[-1])
+            if step % args.log_every == 0 or step == args.steps - 1:
+                print(f"step {step:5d} loss {loss:.4f} "
+                      f"gnorm {gnorm:.3f} {dt*1e3:.0f}ms")
+            if ckpt and step and step % args.checkpoint_every == 0:
+                with span("train/checkpoint"):
+                    ckpt.save(step, (params, opt_state))
     if ckpt:
-        ckpt.save(args.steps, (params, opt_state))
+        with span("train/checkpoint"):
+            ckpt.save(args.steps, (params, opt_state))
         ckpt.wait()
     if monitor.flagged:
         print(f"straggler steps flagged: {monitor.flagged} "

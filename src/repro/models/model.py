@@ -143,25 +143,39 @@ def init_params(cfg: ModelConfig, key) -> PyTree:
 # Blocks (train / prefill)
 
 
+# Name scopes (HLO op metadata, free on the device): model/embed (tokens
+# and their positions), model/stack around the layer scan, inside it
+# model/attention, model/mlp, model/moe and model/ssm, then model/head.  A
+# forward scope stays on the operations of its backward and of its
+# recomputed (remat) forward, so each scope holds its layer's whole cost;
+# the benchmark's trace readers (bench/metrics) match them.
+
+
+def _attention_half(cfg: ModelConfig, p, x, positions, unroll):
+    with jax.named_scope("model/attention"):
+        h = rms_norm(x, p["norm1"], cfg.norm_eps)
+        return x + attn_lib.attention_block(cfg, p["attn"], h, positions,
+                                            unroll=unroll)
+
+
 def _dense_block(cfg: ModelConfig, p, x, positions, unroll):
-    h = rms_norm(x, p["norm1"], cfg.norm_eps)
-    x = x + attn_lib.attention_block(cfg, p["attn"], h, positions, unroll=unroll)
-    h = rms_norm(x, p["norm2"], cfg.norm_eps)
-    x = x + gated_mlp(cfg, p["mlp"], h)
-    return x
+    x = _attention_half(cfg, p, x, positions, unroll)
+    with jax.named_scope("model/mlp"):
+        h = rms_norm(x, p["norm2"], cfg.norm_eps)
+        return x + gated_mlp(cfg, p["mlp"], h)
 
 
 def _moe_block(cfg: ModelConfig, p, x, positions, unroll):
-    h = rms_norm(x, p["norm1"], cfg.norm_eps)
-    x = x + attn_lib.attention_block(cfg, p["attn"], h, positions, unroll=unroll)
-    h = rms_norm(x, p["norm2"], cfg.norm_eps)
-    x = x + moe_lib.moe_block(cfg, p["moe"], h)
-    return x
+    x = _attention_half(cfg, p, x, positions, unroll)
+    with jax.named_scope("model/moe"):
+        h = rms_norm(x, p["norm2"], cfg.norm_eps)
+        return x + moe_lib.moe_block(cfg, p["moe"], h)
 
 
 def _mamba_layer(cfg: ModelConfig, p, x, unroll):
-    h = rms_norm(x, p["norm"], cfg.norm_eps)
-    return x + ssm_lib.mamba_block(cfg, p["mixer"], h, unroll=unroll)
+    with jax.named_scope("model/ssm"):
+        h = rms_norm(x, p["norm"], cfg.norm_eps)
+        return x + ssm_lib.mamba_block(cfg, p["mixer"], h, unroll=unroll)
 
 
 def _shared_attn_apply(cfg: ModelConfig, p, x, positions, unroll):
@@ -223,19 +237,20 @@ def _hybrid_stack(cfg, params, x, positions, unroll):
 
 
 def embed_tokens(cfg: ModelConfig, params, batch):
-    dt = _dtype(cfg)
-    if not cfg.embed_inputs:
-        x = batch["embeds"].astype(dt)          # modality-frontend stub
-    elif cfg.num_codebooks:
-        toks = batch["tokens"]                   # (B, S, K)
-        emb = params["embed"]                    # (K, V, D)
-        x = sum(emb[i][toks[..., i]] for i in range(cfg.num_codebooks))
-        x = x.astype(dt)
-    else:
-        x = params["embed"][batch["tokens"]].astype(dt)
-    if cfg.embed_scale:
-        x = x * jnp.asarray(cfg.d_model ** 0.5, dt)
-    return constrain(x, "batch", "seq", None)
+    with jax.named_scope("model/embed"):
+        dt = _dtype(cfg)
+        if not cfg.embed_inputs:
+            x = batch["embeds"].astype(dt)          # modality-frontend stub
+        elif cfg.num_codebooks:
+            toks = batch["tokens"]                   # (B, S, K)
+            emb = params["embed"]                    # (K, V, D)
+            x = sum(emb[i][toks[..., i]] for i in range(cfg.num_codebooks))
+            x = x.astype(dt)
+        else:
+            x = params["embed"][batch["tokens"]].astype(dt)
+        if cfg.embed_scale:
+            x = x * jnp.asarray(cfg.d_model ** 0.5, dt)
+        return constrain(x, "batch", "seq", None)
 
 
 def _positions(cfg: ModelConfig, batch, B, S):
@@ -251,31 +266,39 @@ def forward(cfg: ModelConfig, params, batch, *, unroll: bool = False):
     """Returns logits: (B, S, V) or (B, S, K, V) for codebook models."""
     x = embed_tokens(cfg, params, batch)
     B, S, _ = x.shape
-    positions = _positions(cfg, batch, B, S)
+    with jax.named_scope("model/embed"):
+        positions = _positions(cfg, batch, B, S)
 
-    fam = cfg.family
-    if fam in ("dense", "vlm", "audio"):
-        x = _run_stack(cfg, params["layers"], x, positions,
-                       lambda p, v: _dense_block(cfg, p, v, positions, unroll),
-                       unroll)
-    elif fam == "moe":
-        if cfg.first_dense_layers:
-            x = _run_stack(cfg, params["dense_layers"], x, positions,
-                           lambda p, v: _dense_block(cfg, p, v, positions, unroll),
+    def dense(p, v):
+        return _dense_block(cfg, p, v, positions, unroll)
+
+    # model/stack: the layer scan's own work (each layer's parameter
+    # slice, its gradient's write into the stacked gradient) around the
+    # blocks' scopes
+    with jax.named_scope("model/stack"):
+        fam = cfg.family
+        if fam in ("dense", "vlm", "audio"):
+            x = _run_stack(cfg, params["layers"], x, positions, dense, unroll)
+        elif fam == "moe":
+            if cfg.first_dense_layers:
+                x = _run_stack(cfg, params["dense_layers"], x, positions,
+                               dense, unroll)
+            x = _run_stack(cfg, params["moe_layers"], x, positions,
+                           lambda p, v: _moe_block(cfg, p, v, positions,
+                                                   unroll),
                            unroll)
-        x = _run_stack(cfg, params["moe_layers"], x, positions,
-                       lambda p, v: _moe_block(cfg, p, v, positions, unroll),
-                       unroll)
-    elif fam == "ssm":
-        x = _run_stack(cfg, params["layers"], x, positions,
-                       lambda p, v: _mamba_layer(cfg, p, v, unroll), unroll)
-    elif fam == "hybrid":
-        x = _hybrid_stack(cfg, params, x, positions, unroll)
-    else:
-        raise ValueError(fam)
+        elif fam == "ssm":
+            x = _run_stack(cfg, params["layers"], x, positions,
+                           lambda p, v: _mamba_layer(cfg, p, v, unroll),
+                           unroll)
+        elif fam == "hybrid":
+            x = _hybrid_stack(cfg, params, x, positions, unroll)
+        else:
+            raise ValueError(fam)
 
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    return project_logits(cfg, params, x)
+    with jax.named_scope("model/head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        return project_logits(cfg, params, x)
 
 
 def project_logits(cfg: ModelConfig, params, x):
@@ -290,15 +313,17 @@ def project_logits(cfg: ModelConfig, params, x):
 
 
 def loss_fn(cfg: ModelConfig, params, batch, *, unroll: bool = False):
-    logits = forward(cfg, params, batch, unroll=unroll).astype(jnp.float32)
-    labels = batch["labels"]
-    logz = jax.scipy.special.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
-    nll = logz - gold
-    mask = batch.get("mask")
-    if mask is not None:
-        while mask.ndim < nll.ndim:
-            mask = mask[..., None]
-        nll = nll * mask
-        return jnp.sum(nll) / jnp.maximum(jnp.sum(mask), 1.0)
-    return jnp.mean(nll)
+    logits = forward(cfg, params, batch, unroll=unroll)
+    with jax.named_scope("model/head"):
+        logits = logits.astype(jnp.float32)
+        labels = batch["labels"]
+        logz = jax.scipy.special.logsumexp(logits, axis=-1)
+        gold = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
+        nll = logz - gold
+        mask = batch.get("mask")
+        if mask is not None:
+            while mask.ndim < nll.ndim:
+                mask = mask[..., None]
+            nll = nll * mask
+            return jnp.sum(nll) / jnp.maximum(jnp.sum(mask), 1.0)
+        return jnp.mean(nll)

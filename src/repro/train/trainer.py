@@ -100,12 +100,19 @@ def make_train_step(cfg: ModelConfig, tx: GradientTransformation, *,
             loss, grads = jax.value_and_grad(loss_of)(params, batch)
         return loss, grads
 
+    def apply_and_norm(params, updates, grads):
+        # the last elementwise passes, under the optimizer's opt/ scopes
+        with jax.named_scope("opt/apply"):
+            new_params = apply_updates(params, updates)
+        with jax.named_scope("opt/grad_norm"):
+            gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
+                                 for g in jax.tree.leaves(grads)))
+        return new_params, gnorm
+
     def train_step(params, opt_state, batch):
         loss, grads = loss_and_grads(params, batch)
         updates, new_opt_state = tx.update(grads, opt_state, params)
-        new_params = apply_updates(params, updates)
-        gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
-                             for g in jax.tree.leaves(grads)))
+        new_params, gnorm = apply_and_norm(params, updates, grads)
         return new_params, new_opt_state, {"loss": loss, "grad_norm": gnorm}
 
     if data_parallel_mesh is None:
@@ -130,9 +137,7 @@ def make_train_step(cfg: ModelConfig, tx: GradientTransformation, *,
             grads = dreduce.pmean(grads, a)
         with dreduce.local_gradients(grads_local):
             updates, new_opt_state = tx.update(grads, opt_state, params)
-        new_params = apply_updates(params, updates)
-        gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
-                             for g in jax.tree.leaves(grads)))
+        new_params, gnorm = apply_and_norm(params, updates, grads)
         return new_params, new_opt_state, {"loss": loss, "grad_norm": gnorm}
 
     def sharded_train_step(params, opt_state, batch):

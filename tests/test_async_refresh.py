@@ -112,22 +112,6 @@ def test_async_direction_is_one_refresh_stale():
     assert diverged, "async directions never lagged inline — no pipelining"
 
 
-def test_profile_annotations_bitwise_noop():
-    """Trace spans are observability only — bitwise identical states."""
-    params = _params()
-    for mode in ("inline", "async"):
-        mk = lambda ann: sketchy(SketchyConfig(
-            rank=6, block_size=16, update_every=2, refresh_mode=mode,
-            profile_annotations=ann))
-        tx0, tx1 = mk(False), mk(True)
-        s0, s1 = tx0.init(params), tx1.init(params)
-        for t in range(4):
-            g = _grads(t, params)
-            d0, s0 = tx0.update(g, s0, params)
-            d1, s1 = tx1.update(g, s1, params)
-            _leaves_equal((d0, s0), (d1, s1), f"annotations changed {mode}")
-
-
 def test_pending_slot_excluded_from_memory_accounting():
     """The double buffer is transient: paper-metric second-moment bytes are
     identical across refresh modes (Fig. 1 numbers don't move)."""
