@@ -10,7 +10,10 @@ TPU adaptation (DESIGN.md §3): instead of eigendecomposing the d x d matrix
 (Alg. 1 line 3) or SVD-ing the d x (ell+r) stack (paper §6), we
 eigendecompose the (ell+r) x (ell+r) Gram matrix of ``M = [sqrt(beta2)*B, A]``
 — one tall-skinny MXU matmul plus a small eigh. Identical result, never
-materializes d x d, and avoids large-matrix SVD which TPUs lack.
+materializes d x d, and avoids large-matrix SVD which TPUs lack.  Only the
+leading ``ell`` eigenpairs of the Gram are used, and on TPU only they are
+computed (``core/top_eigh.py``: the QDWH recursion skips the eigenspaces
+below them); elsewhere the full spectrum is solved and sliced.
 
 Kernel injection: every function takes an optional
 ``kernels: repro.kernels.registry.KernelSet``.  The single-block entry
@@ -29,10 +32,12 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-# The batched paths name their Gram ``fd/gram`` and its eigendecomposition
-# ``fd/eigh`` (HLO op metadata, free on the device; ``fd/eigh`` wraps the
-# ``eigh`` call and nothing else, so the benchmark's trace readers find the
-# solver by it).
+from repro.core.top_eigh import TERMINATION_SIZE, top_eigh
+
+# The batched paths name their Gram ``fd/gram``, and every path names its
+# eigendecomposition ``fd/eigh`` (HLO op metadata, free on the device;
+# ``fd/eigh`` wraps the eigensolver and nothing else, so the benchmark's
+# trace readers find the solver by it).
 #
 # Every contraction on the FD path runs at full f32: a TPU's default f32
 # dot is one bf16 pass, and the Gram feeds an eigh whose small eigenvalues
@@ -44,6 +49,32 @@ class FDState(NamedTuple):
     eigvecs: jnp.ndarray  # (d, ell) approximate top eigenvectors U
     eigvals: jnp.ndarray  # (ell,) deflated eigenvalues, descending, last == 0
     rho: jnp.ndarray      # scalar: accumulated escaped mass rho_{1:t}
+
+
+def _top_eigh(C: jnp.ndarray, ell: int):
+    """Leading ``ell`` eigenpairs of each symmetric ``C (..., n, n)``:
+    eigenvalues ``(..., ell)`` descending and clipped at 0 (round-off makes
+    tiny negatives), eigenvectors ``(..., n, ell)``.
+
+    On TPU only those are solved (``top_eigh``, the same QDWH splits the full
+    solve makes for them); elsewhere, and where the TPU would solve the
+    whole matrix as one Jacobi block anyway, the full spectrum is solved and
+    sliced.
+    """
+    def full(C):
+        with jax.named_scope("fd/eigh"):
+            lam, V = jnp.linalg.eigh(C)          # ascending
+        return (jnp.maximum(lam[..., ::-1][..., :ell], 0.0),
+                V[..., ::-1][..., :ell])
+
+    def top(C):
+        with jax.named_scope("fd/eigh"):
+            lam, V = top_eigh(C, ell)            # ascending
+        return jnp.maximum(lam[..., ::-1], 0.0), V[..., ::-1]
+
+    if C.shape[-1] <= TERMINATION_SIZE or ell >= C.shape[-1]:
+        return full(C)
+    return jax.lax.platform_dependent(C, tpu=top, default=full)
 
 
 def fd_init(d: int, ell: int, dtype=jnp.float32) -> FDState:
@@ -94,18 +125,14 @@ def fd_update(state: FDState, new_factor: jnp.ndarray, beta2: float = 1.0,
         C = kernels.gram(M)
     C = 0.5 * (C + C.T)  # symmetrize for eigh stability
 
-    lam, V = jnp.linalg.eigh(C)          # ascending
-    lam = jnp.maximum(lam[::-1], 0.0)    # descending, clip tiny negatives
-    V = V[:, ::-1]
-
-    lam_top = lam[:ell]
+    lam_top, V = _top_eigh(C, ell)
     # Escaped eigenvalue: lambda_ell of the un-deflated update. When the
     # stacked matrix has rank <= ell the escaped mass is genuinely 0 (it is
     # lam[ell-1] only after deflation below keeps the invariant s[-1] == 0).
     rho_t = lam_top[ell - 1]
 
     inv_sqrt = jnp.where(lam_top > 1e-30, jax.lax.rsqrt(jnp.maximum(lam_top, 1e-30)), 0.0)
-    U_new = jnp.matmul(M, V[:, :ell], precision=_HIGHEST) * inv_sqrt[None, :]
+    U_new = jnp.matmul(M, V, precision=_HIGHEST) * inv_sqrt[None, :]
     s_new = lam_top - rho_t  # deflate: last entry becomes exactly 0
 
     return FDState(
@@ -174,18 +201,12 @@ def fd_update_batched(state: FDState, new_factor: jnp.ndarray,
             C = kernels.batched_gram(M)
         C = 0.5 * (C + jnp.swapaxes(C, -1, -2))
 
-    with jax.named_scope("fd/eigh"):
-        lam, V = jnp.linalg.eigh(C)         # ascending, batched
-    lam = jnp.maximum(lam[..., ::-1], 0.0)  # descending, clip tiny negatives
-    V = V[..., ::-1]
-
-    lam_top = lam[..., :ell]
+    lam_top, V = _top_eigh(C, ell)
     rho_t = _escaped_eigval(lam_top, active_k, ell)   # (N,)
 
     inv_sqrt = jnp.where(lam_top > 1e-30,
                          jax.lax.rsqrt(jnp.maximum(lam_top, 1e-30)), 0.0)
-    U_new = jnp.matmul(M, V[..., :ell], precision=_HIGHEST) \
-        * inv_sqrt[:, None, :]
+    U_new = jnp.matmul(M, V, precision=_HIGHEST) * inv_sqrt[:, None, :]
     s_new = lam_top - rho_t[..., None]
     if kmask is not None:
         U_new = jnp.where(kmask[:, None, :], U_new, 0.0)
@@ -250,12 +271,7 @@ def _fd_update_batched_quantized(U, s, rho, new_factor, beta2, kernels,
             C = kernels.batched_gram_mixed(vq, colw, A)
         C = 0.5 * (C + jnp.swapaxes(C, -1, -2))
 
-    with jax.named_scope("fd/eigh"):
-        lam, V = jnp.linalg.eigh(C)         # ascending, batched
-    lam = jnp.maximum(lam[..., ::-1], 0.0)  # descending, clip tiny negatives
-    V = V[..., ::-1]
-
-    lam_top = lam[..., :ell]
+    lam_top, V = _top_eigh(C, ell)
     rho_t = _escaped_eigval(lam_top, active_k, ell)   # (N,)
 
     inv_sqrt = jnp.where(lam_top > 1e-30,
@@ -263,7 +279,7 @@ def _fd_update_batched_quantized(U, s, rho, new_factor, beta2, kernels,
     # U_new = M @ W with M = [Vq diag(colw), A]: split W by row block and
     # fold the column weights into the top half so the projection consumes
     # the raw int8 values directly
-    W = V[..., :ell] * inv_sqrt[:, None, :]           # (N, ell+r, ell)
+    W = V * inv_sqrt[:, None, :]                      # (N, ell+r, ell)
     if kmask is not None:
         # masking W's output columns keeps inactive eigenvector columns at
         # zero through the in-kernel quantization as well
@@ -374,18 +390,12 @@ def fd_merge_factors_batched(Ba: jnp.ndarray, rho_a: jnp.ndarray,
             C = kernels.batched_gram(M)
         C = 0.5 * (C + jnp.swapaxes(C, -1, -2))
 
-    with jax.named_scope("fd/eigh"):
-        lam, V = jnp.linalg.eigh(C)         # ascending, batched
-    lam = jnp.maximum(lam[..., ::-1], 0.0)  # descending, clip tiny negatives
-    V = V[..., ::-1]
-
-    lam_top = lam[..., :ell]
+    lam_top, V = _top_eigh(C, ell)
     rho_t = lam_top[..., ell - 1]           # (N,) escaped eigenvalue
 
     inv_sqrt = jnp.where(lam_top > 1e-30,
                          jax.lax.rsqrt(jnp.maximum(lam_top, 1e-30)), 0.0)
-    U_new = jnp.matmul(M, V[..., :ell], precision=_HIGHEST) \
-        * inv_sqrt[:, None, :]
+    U_new = jnp.matmul(M, V, precision=_HIGHEST) * inv_sqrt[:, None, :]
     s_new = lam_top - rho_t[..., None]      # deflate: last entry exactly 0
 
     return FDState(eigvecs=U_new, eigvals=s_new,

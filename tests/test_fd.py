@@ -1,6 +1,7 @@
 """Property tests for the Frequent Directions core (paper Alg. 1, Lemma 1,
 Observation 6)."""
 import jax
+import jax.extend.core as jax_core
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -9,9 +10,11 @@ try:
 except ImportError:  # fall back to the deterministic sampling shim
     from hypothesis_compat import given, settings, strategies as st
 
+from repro.core import fd, quantize
 from repro.core.fd import (FDState, fd_apply_inverse_root, fd_covariance,
                            fd_init, fd_merge, fd_merge_batched, fd_update,
                            fd_weighted_factor)
+from repro.core.top_eigh import TERMINATION_SIZE, top_eigh
 
 jax.config.update("jax_enable_x64", False)
 
@@ -242,3 +245,143 @@ def test_inverse_root_apply_matches_dense(exponent):
     lam, V = np.linalg.eigh(dense)
     want = (V * lam ** exponent) @ V.T @ np.asarray(X, np.float64)
     np.testing.assert_allclose(np.asarray(got), want, rtol=2e-3, atol=2e-4)
+
+
+# --- the top-ell eigensolve (core/top_eigh.py, fd._top_eigh) ---------------
+
+def _previous_top_eigh(C, ell):
+    """The refresh's solve before it asked for the top ell alone: the full
+    spectrum, reversed to descending, clipped, sliced."""
+    lam, V = jnp.linalg.eigh(C)
+    lam = jnp.maximum(lam[..., ::-1], 0.0)
+    V = V[..., ::-1]
+    return lam[..., :ell], V[..., :ell]
+
+
+def _fd_gram_inputs(N=2, d=40, ell=8, r=264, seed=0):
+    """A refreshed sketch stack and the next factor; its Grams are ell + r
+    wide, past one Jacobi block, so the platform choice is traced."""
+    rng = np.random.default_rng(seed)
+    A0 = jnp.asarray(rng.normal(size=(N, d, r)), jnp.float32)
+    A1 = jnp.asarray(rng.normal(size=(N, d, r)) * np.linspace(0.2, 2, r),
+                     jnp.float32)
+    state = fd.fd_update_batched(FDState(jnp.zeros((N, d, ell)),
+                                         jnp.zeros((N, ell)), jnp.zeros(N)),
+                                 A0, beta2=0.999)
+    return state, A1
+
+
+_TOP_PATHS = {
+    "update_batched": lambda st, A: fd.fd_update_batched(st, A, beta2=0.999),
+    "update_batched_active_k": lambda st, A: fd.fd_update_batched(
+        st, A, beta2=0.999, active_k=jnp.array([3, 8])),
+    "update_batched_int8": lambda st, A: fd.fd_update_batched(
+        st._replace(eigvecs=quantize.quantize_stack(st.eigvecs)), A,
+        beta2=0.999),
+    "merge_factors_batched": lambda st, A: fd.fd_merge_factors_batched(
+        fd_weighted_factor(st), st.rho, A[..., :260], st.rho, ell=8),
+    "update_single": lambda st, A: fd_update(
+        jax.tree.map(lambda x: x[0], st), A[0], beta2=0.999),
+}
+
+
+@pytest.mark.parametrize("path", sorted(_TOP_PATHS))
+def test_top_eigh_matches_previous_expression(path, monkeypatch):
+    """Off TPU every FD path returns, bit for bit, what the full solve,
+    reversed and sliced, gave it."""
+    state, A = _fd_gram_inputs()
+    fn = _TOP_PATHS[path]
+    got = jax.jit(lambda s, a: fn(s, a))(state, A)
+    monkeypatch.setattr(fd, "_top_eigh", _previous_top_eigh)
+    want = jax.jit(lambda s, a: fn(s, a))(state, A)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+def _subjaxprs(eqn):
+    """The jaxprs an equation holds (branches, loop bodies, calls)."""
+    for p in eqn.params.values():
+        for sub in p if isinstance(p, (tuple, list)) else (p,):
+            if isinstance(sub, jax_core.ClosedJaxpr):
+                sub = sub.jaxpr
+            if isinstance(sub, jax_core.Jaxpr):
+                yield sub
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs nested in it."""
+    for e in jaxpr.eqns:
+        yield e
+        for sub in _subjaxprs(e):
+            yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("d,r", [(1024, 768), (768, 1024)])
+def test_tpu_refresh_solves_only_top_ell(d, r):
+    """The Grams of the 100M LM's blocks (832 and 1088 wide, ell 64): on TPU
+    the refresh runs the top-ell recursion, whose only ``eigh`` calls are
+    its unsorted Jacobi blocks, all under ``fd/eigh``; elsewhere one full
+    ``eigh`` of the stack."""
+    N, ell = 2, 64
+    n = ell + r
+    jx = jax.make_jaxpr(fd.fd_update_batched)(
+        FDState(jnp.zeros((N, d, ell)), jnp.zeros((N, ell)), jnp.zeros(N)),
+        jnp.zeros((N, d, r)))
+    (cond,) = [e for e in jx.jaxpr.eqns if e.primitive.name == "cond"
+               and "branches_platforms" in e.params]
+    platforms = cond.params["branches_platforms"]
+    branch = lambda p: cond.params["branches"][platforms.index(p)].jaxpr
+    tpu, default = branch(("tpu",)), branch(None)
+
+    full = [e for e in _eqns(default) if e.primitive.name == "eigh"]
+    assert [e.invars[0].aval.shape for e in full] == [(N, n, n)]
+
+    blocks = [e for e in _eqns(tpu) if e.primitive.name == "eigh"]
+    assert blocks
+    for e in blocks:
+        assert e.invars[0].aval.shape == (TERMINATION_SIZE, TERMINATION_SIZE)
+        assert not e.params["sort_eigenvalues"]
+        assert e.params["subset_by_index"] is None
+    solver = [e for e in tpu.eqns
+              if any(q.primitive.name == "eigh"
+                     for sub in _subjaxprs(e) for q in _eqns(sub))]
+    assert solver and all("fd/eigh" in str(e.source_info.name_stack)
+                          for e in solver)
+    assert [v.aval.shape for v in tpu.outvars] == [(N, ell), (N, n, ell)]
+    assert [v.aval.shape for v in default.outvars] == [(N, ell), (N, n, ell)]
+
+
+@pytest.mark.parametrize("k", [8, 30, 48])
+def test_top_eigh_recursion_matches_full_recursion(k):
+    """``top_eigh``'s skipping, checked where it can run off TPU: with the
+    recursion taken down to 1x1 blocks (no Jacobi step, whose CPU stand-in
+    sorts and so breaks the padded base case) it returns the full
+    recursion's top k, bit for bit, and float64's eigenvalues to
+    round-off.  k = 8 skips most of the spectrum, k = 30 keeps a part below
+    the first split, k = n skips nothing."""
+    from jax._src.tpu.linalg import eigh as qdwh_eigh
+    d, ell, r = 96, 8, 40
+    n = ell + r
+    rng = np.random.default_rng(k)
+    Cs = []
+    for _ in range(2):
+        U = np.linalg.qr(rng.normal(size=(d, ell)))[0]
+        s = np.sort(rng.uniform(1, 50, ell))[::-1]
+        s[-1] = 0.0
+        M = np.concatenate([U * np.sqrt(0.999 * s),
+                            rng.normal(size=(d, r)) * rng.uniform(0.1, 1, r)],
+                           axis=1).astype(np.float32)
+        C = M.T @ M
+        Cs.append(0.5 * (C + C.T))
+    C = jnp.asarray(np.stack(Cs))
+    w, v = jax.jit(lambda C: top_eigh(C, k, termination_size=1))(C)
+    assert w.shape == (2, k) and v.shape == (2, n, k)
+    for i in range(2):
+        fw, fv = qdwh_eigh.eigh(C[i], termination_size=1)
+        np.testing.assert_array_equal(np.asarray(w[i]), np.asarray(fw[-k:]))
+        np.testing.assert_array_equal(np.asarray(v[i]),
+                                      np.asarray(fv[:, -k:]))
+        ref = np.linalg.eigvalsh(np.asarray(C[i], np.float64))[-k:]
+        np.testing.assert_allclose(np.asarray(w[i]), ref,
+                                   rtol=0, atol=1e-5 * ref[-1])
