@@ -5,7 +5,8 @@ untied output head.  Written from the description in
 ``paper-lm-100m.json``; it imports nothing of the program.
 
 ``loss_sum`` returns the summed next-token loss of the given rows, each
-token weighted by its row's weight.
+token weighted by its row's weight; ``flops_per_token`` counts the training
+step's matmul work.
 """
 import jax
 import jax.numpy as jnp
@@ -26,6 +27,23 @@ def param_shapes(model: dict) -> dict:
     }
     return {"embed": (V, D), "lm_head": (D, V), "final_norm": (D,),
             "layers": reflib.stack(layer, L)}
+
+
+def flops_per_token(model: dict, seq: int) -> float:
+    """Training FLOPs per token: 6 per parameter of every matrix the tokens
+    are multiplied by (the input embedding gather is a lookup and is not
+    counted), plus 3 x the forward's causal attention contractions;
+    recomputation is not counted."""
+    D, V, L = model["hidden_size"], model["vocab_size"], \
+        model["num_hidden_layers"]
+    H, KV, hd = (model["num_attention_heads"], model["num_key_value_heads"],
+                 model["head_dim"])
+    per_layer = D * (H + 2 * KV) * hd + H * hd * D \
+        + 3 * D * model["intermediate_size"]
+    matmul_params = L * per_layer + D * V          # head; gather skipped
+    # causal attention: q.k and p.v, 2 * hd * (S / 2) each, per head
+    attn = L * 2 * 2 * H * hd * (seq / 2)
+    return 6 * matmul_params + 3 * attn
 
 
 def _rope(x, theta):

@@ -1,17 +1,19 @@
-"""Operations and bytes from shapes: the model's training FLOPs per token,
-and each optimizer kernel call's nominal operations and least bytes.
+"""Operations and bytes from shapes: each optimizer kernel call's nominal
+operations and least bytes, the chip's peaks, and the counts a traced run
+takes from a cell's shapes alone.
 
-Nothing here reads the program.  The model count is the matmul work the
-forward and backward passes require (6 per parameter of every matrix the
-tokens are multiplied by, plus attention's contractions); recomputation is not counted, nor is the input embedding
-gather, which is a lookup and not a matmul (a tied embedding is counted
-once, as the output head).  A kernel's least bytes read each input once
-and write each output once.
+Nothing here reads the program.  The model's training FLOPs per token are
+each configuration's own: ``flops_per_token(model, seq)`` in its plain
+reference ``bench/configs/<config>.py``, so that a configuration of any
+family joins as new files.  A kernel's least bytes read each input once and
+write each output once.
 """
 from __future__ import annotations
 
 import json
 import os
+
+import jax
 
 from bench import reflib
 
@@ -29,23 +31,6 @@ def peaks(device_kind: str) -> dict:
     return table[device_kind]
 
 
-def model_flops_per_token(config: dict) -> float:
-    """Training FLOPs per token of the configuration's model."""
-    m, S = config["model"], config["seq"]
-    fam = config["family"]
-    if fam == "dense":
-        D, V, L = m["hidden_size"], m["vocab_size"], m["num_hidden_layers"]
-        H, KV, hd = (m["num_attention_heads"], m["num_key_value_heads"],
-                     m["head_dim"])
-        per_layer = D * (H + 2 * KV) * hd + H * hd * D \
-            + 3 * D * m["intermediate_size"]
-        matmul_params = L * per_layer + D * V          # head; gather skipped
-        # causal attention: q.k and p.v, 2 * hd * (S / 2) each, per head
-        attn = L * 2 * 2 * H * hd * (S / 2)
-        return 6 * matmul_params + 3 * attn
-    raise ValueError(f"no FLOP count for family {fam!r}")
-
-
 def pool_groups(param_shapes: list, block_size: int) -> dict:
     """``{(bm, bn): N}``: how many blocks of each shape the model's matrix
     leaves make (zero-padded tiles of at most ``block_size``)."""
@@ -57,6 +42,19 @@ def pool_groups(param_shapes: list, block_size: int) -> dict:
         S, _, _, bm, bn, mb, nb = lay
         groups[(bm, bn)] = groups.get((bm, bn), 0) + S * mb * nb
     return groups
+
+
+def shape_counts(cell) -> dict:
+    """What a traced run of ``cell`` computes from shapes alone:
+    ``flops_per_token`` (the configuration's reference counts it),
+    ``pool_groups`` and ``rank`` of the mix."""
+    h, c = cell.mix["hyper"], cell.config
+    shapes = jax.tree.leaves(cell.model_ref.param_shapes(c["model"]),
+                             is_leaf=lambda x: isinstance(x, tuple))
+    return {"flops_per_token": float(cell.model_ref.flops_per_token(
+                c["model"], c["seq"])),
+            "pool_groups": pool_groups(shapes, h.get("block_size", 1)),
+            "rank": h.get("rank", 0)}
 
 
 def gram_calls(groups: dict, rank: int) -> list:

@@ -156,16 +156,11 @@ def run(argv=None, require_tpu: bool = True, fault: str = None,
         shutil.rmtree(trace_dir, ignore_errors=True)
         dev = sorted(tr.ops)[0]
         busy, win = trace_lib.busy_and_window_s(tr)
-        h = cell.mix["hyper"]
-        shapes = jax.tree.leaves(cell.model_ref.param_shapes(
-            cell.config["model"]), is_leaf=lambda x: isinstance(x, tuple))
         ctx = Context(
             trace=tr, device=dev, steps=steps,
             tokens_per_s=tokens / elapsed, chips=len(used),
             peak=flops.peaks(devices[0].device_kind),
-            flops_per_token=flops.model_flops_per_token(cell.config),
-            pool_groups=flops.pool_groups(shapes, h.get("block_size", 1)),
-            rank=h.get("rank", 0))
+            **flops.shape_counts(cell))
         metrics = {}
         for m in cell.per_layer():
             v = cell.metric_reader(m["name"]).read(ctx)

@@ -4,7 +4,8 @@ Everything that belongs to one configuration, one mix, one per-layer
 metric or one cell sits in a file of its own:
 
 * ``bench/configs/<config>.json``  sizes and settings as run, with its plain
-  reference ``bench/configs/<config>.py`` beside it;
+  reference ``bench/configs/<config>.py`` beside it, which defines
+  ``param_shapes``, ``loss_sum`` and ``flops_per_token``;
 * ``bench/mixes/<traffic>.json``   the job: optimizer, its settings, the
   steps of one period;
 * ``bench/optimizers/<optimizer>.py``  the optimizer's plain reference;
@@ -28,6 +29,10 @@ ROOT = os.path.dirname(BENCH)
 
 NAME_RE = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT_RE = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
+
+
+# what every configuration's plain reference defines
+MODEL_REF_API = ("param_shapes", "loss_sum", "flops_per_token")
 
 
 class SpecError(Exception):
@@ -77,9 +82,14 @@ class Cell:
         self.mix = load_json(_file(root, "mixes", self.workload["traffic"],
                                    ".json"))
         self.limits = load_json(_file(root, "limits", workload, ".json"))
+        ref_path = _file(root, "configs", self.workload["config"], ".py")
         self.model_ref = load_module(
-            _file(root, "configs", self.workload["config"], ".py"),
-            "bench_ref_" + re.sub(r"\W", "_", self.workload["config"]))
+            ref_path, "bench_ref_" + re.sub(r"\W", "_",
+                                            self.workload["config"]))
+        missing = [f for f in MODEL_REF_API if not hasattr(self.model_ref, f)]
+        if missing:
+            raise SpecError(f"{os.path.relpath(ref_path, root)} defines no "
+                            f"{', '.join(missing)}")
         self.opt_ref = load_module(
             _file(root, "optimizers", self.mix["optimizer"], ".py"),
             "bench_opt_" + self.mix["optimizer"])

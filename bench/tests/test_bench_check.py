@@ -26,7 +26,8 @@ def _run(root, workload, fault=None, trace=0):
                        require_tpu=False, fault=fault, root=root)
 
 
-@pytest.mark.parametrize("workload", ["tiny-lm.sketchy", "tiny-lm.adam"])
+@pytest.mark.parametrize("workload", ["tiny-lm.sketchy", "tiny-lm.adam",
+                                      "tiny-ssm.sketchy", "tiny-ssm.adam"])
 def test_sound_run_is_correct(root, workload):
     res = _run(root, workload)
     assert res["correct"] is True
@@ -44,18 +45,31 @@ def test_sound_run_is_correct(root, workload):
     assert res["attempted"] % period == 0 and res["attempted"] >= period
 
 
-@pytest.mark.parametrize("fault", harness.FAULTS)
-def test_broken_step_is_not_correct(root, fault):
-    res = _run(root, "tiny-lm.sketchy", fault=fault)
+@pytest.mark.parametrize("workload,fault", [
+    pytest.param(w, f, id=f if w == "tiny-lm.sketchy" else f"{w}-{f}")
+    for w in ("tiny-lm.sketchy", "tiny-ssm.sketchy") for f in harness.FAULTS])
+def test_broken_step_is_not_correct(root, workload, fault):
+    res = _run(root, workload, fault=fault)
     assert res["correct"] is False, res["checks"]
 
 
-def test_control_fails_the_limits(root):
-    cell = spec_lib.Cell(spec_lib.load_spec(root), "tiny-lm.sketchy", root)
+def _control_verdict(root, workload):
+    cell = spec_lib.Cell(spec_lib.load_spec(root), workload, root)
     harness.setup_jax(root, cache=False)
     ref = harness.reference_readings(cell, SEED)
     ctl = harness.reference_readings(cell, SEED, mode="control")
-    correct, table = harness.verdict(harness.compare(ctl, ref), cell.limits)
+    return harness.verdict(harness.compare(ctl, ref), cell.limits)
+
+
+def test_control_fails_the_limits(root):
+    correct, table = _control_verdict(root, "tiny-lm.sketchy")
+    assert correct is False, table
+
+
+def test_ssm_control_fails_the_limits(root):
+    """The Mamba2 reference one precision down (float8 matmuls, the
+    recurrence's included) in the program's place."""
+    correct, table = _control_verdict(root, "tiny-ssm.sketchy")
     assert correct is False, table
 
 

@@ -5,6 +5,7 @@ import os
 import pytest
 
 from bench import flops, reflib, spec as spec_lib
+from bench.tests import tiny
 
 
 def _config(name):
@@ -12,22 +13,74 @@ def _config(name):
         return json.load(f)
 
 
+def _ref(name):
+    if name == "tiny-ssm":
+        path = os.path.join(os.path.dirname(__file__), "mamba2_ref.py")
+        return tiny.TINY_SSM, spec_lib.load_module(path, "mamba2_ref")
+    path = os.path.join(spec_lib.BENCH, "configs", name + ".py")
+    return _config(name), spec_lib.load_module(path, "ref_" + name)
+
+
 @pytest.mark.parametrize("name,want", [
     # 6 x (12 layers x 9,437,184 + head 768 x 32768) + 3 x 12 x 4 x 12 x 64
     # x 1024 (causal attention); the input embedding gather is not counted
     ("paper-lm-100m", 6 * (12 * 9_437_184 + 768 * 32768)
      + 3 * 12 * 4 * 12 * 64 * 1024),
+    # 6 x (3 layers x (in_proj 64 x (2 x 128 + 2 x 16 + 8) + out_proj 128
+    # x 64) + tied head 64 x 256) + 3 x 3 layers x (Q N 16 x 16 + Q H P
+    # 16 x 8 x 16 + 4 H P N 4 x 8 x 16 x 16), chunk Q 16
+    ("tiny-ssm", 6 * (3 * (64 * 296 + 128 * 64) + 64 * 256)
+     + 3 * 3 * (16 * 16 + 16 * 8 * 16 + 4 * 8 * 16 * 16)),
 ])
 def test_model_flops_per_token(name, want):
-    assert flops.model_flops_per_token(_config(name)) == want
+    config, ref = _ref(name)
+    assert ref.flops_per_token(config["model"], config["seq"]) == want
 
 
-def test_model_flops_values():
-    assert flops.model_flops_per_token(_config("paper-lm-100m")) \
+_API_STUBS = {"param_shapes": "def param_shapes(model):\n    return {}\n",
+              "loss_sum": "def loss_sum(*args):\n    return 0.0\n",
+              "flops_per_token": "def flops_per_token(model, seq):\n"
+                                 "    return 1.0\n"}
+
+
+def test_model_flops_values(tmp_path):
+    config, ref = _ref("paper-lm-100m")
+    assert ref.flops_per_token(config["model"], config["seq"]) \
         == 943_718_400
-    with pytest.raises(ValueError):
-        flops.model_flops_per_token(dict(_config("paper-lm-100m"),
-                                         family="ssm"))
+    # a reference that lacks a part of the API is refused when the cell is
+    # loaded, before anything compiles
+    root = tiny.make_root(tmp_path)
+    spec = spec_lib.load_spec(root)
+    path = os.path.join(root, "bench", "configs", "tiny-lm.py")
+    for missing in spec_lib.MODEL_REF_API:
+        with open(path, "w") as f:
+            f.write("".join(v for k, v in _API_STUBS.items()
+                            if k != missing))
+        with pytest.raises(spec_lib.SpecError,
+                           match=rf"tiny-lm\.py defines no {missing}"):
+            spec_lib.Cell(spec, "tiny-lm.adam", root)
+
+
+def _every_cell(tmp_path):
+    tiny_root = tiny.make_root(tmp_path)
+    for root in (spec_lib.ROOT, tiny_root):
+        spec = spec_lib.load_spec(root)
+        for w in spec["workloads"]:
+            yield spec_lib.Cell(spec, w["name"], root)
+
+
+def test_shape_counts_build_for_every_cell(tmp_path):
+    """What a traced run computes from shapes alone, for the benchmark's
+    cells and the tiny dense and state-space ones."""
+    names = []
+    for cell in _every_cell(tmp_path):
+        counts = flops.shape_counts(cell)
+        assert counts["flops_per_token"] > 0, cell.name
+        assert set(counts) == {"flops_per_token", "pool_groups", "rank"}
+        assert all(n > 0 for n in counts["pool_groups"].values())
+        names.append(cell.name)
+    assert {"paper-lm-100m.sketchy", "tiny-ssm.sketchy",
+            "tiny-ssm.adam"} <= set(names)
 
 
 def test_gram_and_apply_costs():

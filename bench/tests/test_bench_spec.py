@@ -93,6 +93,19 @@ def test_new_files_are_found_by_name(tmp_path):
                                                        "setup_s"}
     assert {m["name"] for m in cell.end_to_end()} == {
         "tokens_per_s", "step_s_max", "setup_s"}
+    # a state-space configuration, its reference and its FLOP count come
+    # from new files alone, as the dense one's do
+    ssm = spec_lib.Cell(spec, "tiny-ssm.sketchy", root)
+    assert ssm.config["family"] == "ssm"
+    assert ssm.config["registry"] == "mamba2-370m"
+    assert ssm.mix["name"] == "tiny-sketchy"
+    assert all(hasattr(ssm.model_ref, f) for f in spec_lib.MODEL_REF_API)
+    assert ssm.model_ref.flops_per_token(ssm.config["model"],
+                                         ssm.config["seq"]) > 0
+    assert "in_proj" in ssm.model_ref.param_shapes(
+        ssm.config["model"])["layers"]["mixer"]
+    assert {m["name"] for m in ssm.per_layer()} \
+        == {m["name"] for m in cell.per_layer()} - {"tiny_probe"}
 
 
 def test_missing_files_are_errors(tmp_path):

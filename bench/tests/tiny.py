@@ -1,9 +1,11 @@
 """A checkout-shaped directory with tiny cells, for the harness's CPU tests.
 
-``make_root(tmp)`` copies ``BENCHMARK.json`` and ``bench/`` and adds two
+``make_root(tmp)`` copies ``BENCHMARK.json`` and ``bench/`` and adds four
 cells at the program's reduced sizes (float32, a few layers of width 64):
-``tiny-lm.sketchy`` and ``tiny-lm.adam``.  They are found by name exactly
-as the real cells are.
+``tiny-lm.sketchy`` and ``tiny-lm.adam`` (dense, the reference of
+paper-lm-100m), ``tiny-ssm.sketchy`` and ``tiny-ssm.adam`` (Mamba2, the
+reference ``mamba2_ref.py`` beside this file).  They are added as new files
+and entries only, and found by name exactly as the real cells are.
 """
 from __future__ import annotations
 
@@ -28,6 +30,25 @@ TINY_LM = {
                        "num_kv_heads": "num_key_value_heads",
                        "head_dim": "head_dim", "d_ff": "intermediate_size",
                        "vocab_size": "vocab_size", "norm_eps": "rms_norm_eps",
+                       "dtype": "torch_dtype"},
+}
+
+# the program's reduced mamba2-370m: 3 layers, d_model 64, 8 heads of 16,
+# state 16; chunks of 16 over 64 tokens carry the state across 4 chunks
+TINY_SSM = {
+    "name": "tiny-ssm", "registry": "mamba2-370m", "program_reduced": True,
+    "family": "ssm", "batch": 4, "seq": 64, "param_dtype": "float32",
+    "reference_rows": 2,
+    "model": {"n_layer": 3, "d_model": 64, "d_state": 16, "d_conv": 4,
+              "expand": 2, "headdim": 16, "ngroups": 1, "chunk_size": 16,
+              "vocab_size": 256, "norm_epsilon": 1e-06,
+              "tie_embeddings": True, "torch_dtype": "float32"},
+    "program_fields": {"num_layers": "n_layer", "d_model": "d_model",
+                       "ssm_state": "d_state", "ssm_conv_width": "d_conv",
+                       "ssm_expand": "expand", "ssm_head_dim": "headdim",
+                       "ssm_chunk": "chunk_size", "vocab_size": "vocab_size",
+                       "norm_eps": "norm_epsilon",
+                       "tie_embeddings": "tie_embeddings",
                        "dtype": "torch_dtype"},
 }
 
@@ -72,15 +93,17 @@ def make_root(tmp: str) -> str:
                     ignore=shutil.ignore_patterns("__pycache__", "tests"))
     spec = spec_lib.load_spec()
     b = os.path.join(root, "bench")
-    _dump(os.path.join(b, "configs", TINY_LM["name"] + ".json"), TINY_LM)
-    shutil.copy(os.path.join(b, "configs", "paper-lm-100m.py"),
-                os.path.join(b, "configs", TINY_LM["name"] + ".py"))
+    refs = [(TINY_LM, os.path.join(b, "configs", "paper-lm-100m.py")),
+            (TINY_SSM, os.path.join(os.path.dirname(__file__),
+                                    "mamba2_ref.py"))]
+    for config, ref in refs:
+        _dump(os.path.join(b, "configs", config["name"] + ".json"), config)
+        shutil.copy(ref, os.path.join(b, "configs", config["name"] + ".py"))
     for mix in (TINY_SKETCHY, TINY_ADAM):
         _dump(os.path.join(b, "mixes", mix["name"] + ".json"), mix)
     # each tiny cell reports what the real cell of its mix reports
-    cells = [("tiny-lm.sketchy", "tiny-lm", "tiny-sketchy",
-              "paper-lm-100m.sketchy"),
-             ("tiny-lm.adam", "tiny-lm", "tiny-adam", "paper-lm-100m.adam")]
+    cells = [(f"{cfg}.{kind}", cfg, f"tiny-{kind}", f"paper-lm-100m.{kind}")
+             for cfg in ("tiny-lm", "tiny-ssm") for kind in ("sketchy", "adam")]
     for name, cfg, mix, twin in cells:
         spec["workloads"].append({"name": name, "config": cfg,
                                   "traffic": mix, "chips": 1,
